@@ -34,6 +34,11 @@ space) is exercised for free.  ``run`` returns the JSON trajectory dict
 (``BENCH_durability.json``): replay throughput, recovery p50/max, and
 the three invariant counters CI fences at zero
 (``tools/check_bench_json.py``).
+
+The lane checks durability invariants, not device speed, so the storm
+child runs on the CPU (``JAX_PLATFORMS=cpu``): it never contends for an
+accelerator that the parent process may hold, and run standalone the
+parent pins itself to the CPU as well.
 """
 from __future__ import annotations
 
@@ -164,6 +169,7 @@ def _kill_round(directory: str, *, dim: int, shards: int, seed: int,
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_REPO_ROOT, "src"), _REPO_ROOT]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    env["JAX_PLATFORMS"] = "cpu"  # a chip belongs to one process
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--dir", directory, "--dim", str(dim), "--shards", str(shards),
@@ -306,6 +312,7 @@ def main(argv=None) -> None:
         assert args.dir, "--child requires --dir"
         _child_main(args)
         return
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before this process imports jax
     res = run(print, smoke=args.smoke)
     import json
 

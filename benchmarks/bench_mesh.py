@@ -16,6 +16,11 @@ query path and emits one JSON line the parent aggregates into
     (the simulated-host curve CI watches; real accelerator meshes are
     the production claim).
 
+Every child runs on the CPU (``JAX_PLATFORMS=cpu``, forced host
+devices): the numbers are a CPU simulation, labelled ``platform: cpu``,
+and a child never contends for an accelerator the parent may hold.  The
+chip's mesh path is ``python chip_smoke.py --chips 4``.
+
 Run:
 
     PYTHONPATH=src python benchmarks/bench_mesh.py
@@ -96,6 +101,7 @@ def _child(devices: int, smoke: bool) -> None:
 
     idx.close()
     print(_RESULT_TAG + json.dumps({
+        "platform": jax.devices()[0].platform,
         "devices": devices,
         "exact": exact,
         "qps": nq * iters / total,
@@ -118,6 +124,7 @@ def _spawn(devices: int, smoke: bool) -> dict:
         f"--xla_force_host_platform_device_count={devices} "
         "--xla_cpu_multi_thread_eigen=false")
     env["OPENBLAS_NUM_THREADS"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"  # simulated devices; a chip is one process
     src = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     extra = env.get("PYTHONPATH")
@@ -138,7 +145,8 @@ def _spawn(devices: int, smoke: bool) -> dict:
 
 
 def run_mesh(smoke: bool = False) -> dict:
-    out: dict = {"device_counts": list(_DEVICE_COUNTS)}
+    out: dict = {"device_counts": list(_DEVICE_COUNTS),
+                 "platform": "cpu (forced host devices: a simulation)"}
     qps = []
     for devices in _DEVICE_COUNTS:
         r = _spawn(devices, smoke)
@@ -155,6 +163,7 @@ def run(csv, *, smoke: bool = False) -> dict:
     """benchmarks.run registry entry point; the returned dict becomes
     ``BENCH_mesh.json``."""
     res = run_mesh(smoke=smoke)
+    csv("mesh,cpu_simulation,1,,,,")
     csv("mesh,devices,qps,p50_ms,p99_ms,fanout,exact")
     for devices in _DEVICE_COUNTS:
         r = res[f"devices_{devices}"]

@@ -58,6 +58,9 @@ def main(argv=None) -> None:
                     help="where BENCH_<lane>.json files are written")
     args = ap.parse_args(argv)
 
+    from repro.launch.platform import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import (bench_ablations, bench_distributed,
                             bench_durability, bench_indexing, bench_kernel,
                             bench_mesh, bench_query, bench_resilience,

@@ -15,14 +15,24 @@ reference oracles.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "EXACT",
     "node_ball_bound",
     "point_ball_bound",
     "query_angle_terms",
     "point_cone_bound",
 ]
+
+
+#: contraction precision of every f32 inner product that a bound or a
+#: distance is built from.  A TPU runs an f32 matmul at DEFAULT precision
+#: as one bf16 pass, whose ~2^-8 relative error would break both the
+#: pruning bounds and the exact answers; HIGHEST keeps f32 accuracy (and
+#: is what the CPU does anyway).
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def node_ball_bound(ip_qc, q_norm, radius):

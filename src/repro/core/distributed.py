@@ -43,7 +43,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import search
 from repro.core.balltree import FlatTree, build_tree
-from repro.parallel.sharding import mesh_signature, shard_map_compat
+from repro.parallel.sharding import mesh_signature
 
 __all__ = ["ShardedP2HIndex", "two_round_exchange", "warm_round1"]
 
@@ -120,15 +120,10 @@ def warm_round1(tree, *, is_bc: bool = True, templates=None) -> int:
                     tree, q, k, use_ball=is_bc, use_cone=is_bc, **kw)
                 np.asarray(bd), np.asarray(bi)  # force compile + execute
                 warmed += 1
-            except Exception:
-                pass  # warming is best-effort; serving stays correct
+            except Exception as e:  # best-effort: serving stays correct
+                from repro.kernels.stacked_sweep import record_warm_failure
+                record_warm_failure("warm_round1", e)
     return warmed
-
-# shard_map moved to the jax top level (and check_rep was renamed to
-# check_vma) in newer releases; the version shim lives in
-# repro.parallel.sharding so the serving-mesh stacked program and this
-# module resolve it identically.
-_shard_map = shard_map_compat
 
 _ARRAY_FIELDS = [
     f.name for f in dataclasses.fields(FlatTree) if not f.metadata.get("static", False)
@@ -782,10 +777,10 @@ def _sharded_query(stacked: FlatTree, queries, lambda_cap, *, mesh, axes, k,
 
     arrays = {f: getattr(stacked, f) for f in _ARRAY_FIELDS}
     in_spec = jax.tree.map(lambda _: P(axes), arrays)
-    out = _shard_map(
+    out = jax.shard_map(
         lambda t, q, cap: local(t, q, cap),
         mesh=mesh,
         in_specs=(in_spec, P(), P()),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P()), check_vma=False,
     )(arrays, queries, lambda_cap)
     return out

@@ -10,12 +10,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.bounds import EXACT
+
 __all__ = ["exact_search", "p2h_dists"]
 
 
 def p2h_dists(points, queries):
     """|<x, q>| for all pairs -> (num_queries, n)."""
-    return jnp.abs(queries @ points.T)
+    return jnp.abs(jnp.dot(queries, points.T, precision=EXACT))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk"))
@@ -37,7 +39,7 @@ def exact_search(points, queries, k: int = 1, chunk: int = 65536):
 
     def step(carry, xc):
         best_d, best_i, off = carry
-        d = jnp.abs(queries @ xc.T)  # (b, chunk)
+        d = jnp.abs(jnp.dot(queries, xc.T, precision=EXACT))  # (b, chunk)
         ids = off + jnp.arange(chunk, dtype=jnp.int32)
         d = jnp.where(ids[None, :] < n, d, jnp.inf)
         md = jnp.concatenate([best_d, d], axis=1)

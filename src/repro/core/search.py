@@ -122,7 +122,7 @@ def _dfs_one(
     qn = jnp.sqrt(jnp.sum(q * q))
     stack_size = tree.max_depth + 3
 
-    ip_root = tree.centers[0] @ q
+    ip_root = jnp.dot(tree.centers[0], q, precision=bounds.EXACT)
     stack_n = jnp.zeros((stack_size,), jnp.int32)
     stack_ip = jnp.zeros((stack_size,), q.dtype).at[0].set(ip_root)
     best_d = jnp.full((k,), jnp.inf, q.dtype)
@@ -151,7 +151,7 @@ def _dfs_one(
             cone_ok = cb < lam
             cnt = cnt.at[C_CONE].add(jnp.sum(keep & ~cone_ok).astype(jnp.int32))
             keep &= cone_ok
-        absip = jnp.abs(blk @ q)
+        absip = jnp.abs(jnp.dot(blk, q, precision=bounds.EXACT))
         cand = jnp.where(keep, absip, jnp.inf)
         cnt = cnt.at[C_VERIFIED].add(jnp.sum(keep).astype(jnp.int32))
         cnt = cnt.at[C_LEAVES].add(1)
@@ -163,7 +163,7 @@ def _dfs_one(
     def _internal(args):
         node, ip, sp, sn, sip, cnt = args
         lc, rc = tree.left[node], tree.right[node]
-        ip_lc = tree.centers[lc] @ q
+        ip_lc = jnp.dot(tree.centers[lc], q, precision=bounds.EXACT)
         if use_collab:  # Lemma 2
             cN = tree.counts[node].astype(q.dtype)
             cL = tree.counts[lc].astype(q.dtype)
@@ -171,7 +171,7 @@ def _dfs_one(
             ip_rc = (cN * ip - cL * ip_lc) / cR
             cnt = cnt.at[C_IP].add(1)
         else:
-            ip_rc = tree.centers[rc] @ q
+            ip_rc = jnp.dot(tree.centers[rc], q, precision=bounds.EXACT)
             cnt = cnt.at[C_IP].add(2)
         if branch == "center":  # paper's default (Section III-C)
             left_first = jnp.abs(ip_lc) < jnp.abs(ip_rc)
@@ -321,7 +321,8 @@ def sweep_search(
     L, n0, d = tree.num_leaves, tree.n0, tree.d
     dtype = queries.dtype
     qn = jnp.sqrt(jnp.sum(queries * queries, axis=1))  # (B,)
-    ipc = queries @ tree.leaf_centers.T  # (B, L)
+    ipc = jnp.dot(queries, tree.leaf_centers.T,
+                  precision=bounds.EXACT)  # (B, L)
     lb_all = bounds.node_ball_bound(ipc, qn[:, None], tree.leaf_radii[None, :])
     # tiles with no valid point (pad_tree_leaves quantization pads,
     # fully-tombstoned tiles): force their bound to +inf so they sort
@@ -375,7 +376,8 @@ def sweep_search(
             )
             keep &= cone_ok
         keep &= ~skip[:, None]
-        absip = jnp.abs(jnp.einsum("bnd,bd->bn", blk, queries))
+        absip = jnp.abs(jnp.einsum("bnd,bd->bn", blk, queries,
+                                   precision=bounds.EXACT))
         cand = jnp.where(keep, absip, jnp.inf)
         cnt = cnt.at[C_VERIFIED].add(jnp.sum(keep).astype(jnp.int32))
         # dead tiles are forced skips, not pruning wins: count neither

@@ -51,7 +51,8 @@ def prepare_operands(tree: FlatTree, queries, *, frac=1.0, bq=8, lambda_cap=None
            else jnp.pad(jnp.asarray(lambda_cap, jnp.float32).reshape(B0, 1),
                         ((0, Bp - B0), (0, 0)), constant_values=jnp.inf))
 
-    ipc = q @ tree.leaf_centers.T  # (Bp, L)
+    ipc = jnp.dot(q, tree.leaf_centers.T,
+                  precision=bounds.EXACT)  # (Bp, L)
     lb = bounds.node_ball_bound(ipc, qn, tree.leaf_radii[None, :])
     # per-query-block center preference: a tile is as promising as its most
     # interested query in the block

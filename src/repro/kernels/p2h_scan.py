@@ -1,8 +1,8 @@
 """Fused P2HNNS sweep kernel (the paper's candidate-verification hot spot).
 
-This is the TPU-native BC-sweep of DESIGN.md section 2, as a single Pallas
-kernel.  One grid step = one leaf *tile* of the flat BC-Tree visited in
-(per-query-block) center-preference order:
+This is the TPU-native BC-sweep of DESIGN.md section 2 over one flat
+BC-Tree.  One grid step = one leaf *tile* visited in (per-query-block)
+center-preference order:
 
   * the tile visit order is a **scalar-prefetch** operand, so the BlockSpec
     ``index_map`` gathers the j-th *preferred* leaf's points/cone tables
@@ -11,150 +11,23 @@ kernel.  One grid step = one leaf *tile* of the flat BC-Tree visited in
     across the sequential grid dimension -- its row-max is the paper's
     ``q.lambda`` pruning threshold, tightening as tiles are consumed;
   * a whole tile is skipped with ``pl.when`` when the **node-level ball
-    bound** (Theorem 2) of every query in the block is >= lambda -- the
-    MXU matmul and all bound math are elided (on real TPU the block DMA is
-    still pipelined in; a manually-pipelined conditional-DMA variant is the
-    natural extension and is discussed in DESIGN.md);
+    bound** (Theorem 2) of every query in the block is >= lambda;
   * inside a live tile, points are pruned with the **point-level ball
     bound** (Corollary 1) and **point-level cone bound** (Theorem 3) before
     the |<x,q>| verification matmul, then ``k`` vectorized insert passes
     update the running top-k.
 
-Tiling: the leaf size ``n0`` is the tile second-minor dim (multiples of 128
-recommended -- MXU-aligned); ``d`` is zero-padded to a lane multiple by
-``ops.py`` (inner products are unchanged).  Queries are processed in blocks
-of ``bq`` (sublane-aligned, default 8) that stay resident in VMEM across
-the whole sweep.
-
-Everything here is shape-static and branch-free except ``pl.when``; the
-pure-jnp oracle with identical semantics is :mod:`repro.kernels.ref`.
+A frozen tree is a one-segment stack, so the sweep runs the stacked
+kernel (:func:`repro.kernels.stacked_sweep.stacked_sweep`) with a
+segment axis of 1 and a cold global top-k: its thresholds are then
+exactly this sweep's.  The pure-jnp oracle with identical semantics is
+:func:`repro.kernels.ref.p2h_sweep_ref`.
 """
 from __future__ import annotations
 
-import functools
+from repro.kernels.stacked_sweep import stacked_sweep
 
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-__all__ = ["p2h_sweep_kernel", "p2h_sweep"]
-
-_NEG_FILL = jnp.inf
-
-
-def _cone_cases(q_cos, q_sin, x_cos, x_sin):
-    """RHS of Inequality 10 (same math as repro.core.bounds._cone_cases)."""
-    a = q_cos * x_cos - q_sin * x_sin
-    b = q_cos * x_cos + q_sin * x_sin
-    zero = jnp.zeros_like(a)
-    return jnp.where((a > 0) & (q_cos > 0) & (x_cos > 0), a,
-                     jnp.where(b < 0, -b, zero))
-
-
-def p2h_sweep_kernel(
-    # scalar prefetch
-    visit_ref,  # (nqb, L) i32 -- per-query-block leaf visit order
-    # inputs (blocked)
-    q_ref,      # (bq, dp) f32 -- query block (resident across sweep)
-    qn_ref,     # (bq, 1)  f32 -- ||q||
-    cap_ref,    # (bq, 1)  f32 -- external lambda cap (distributed search)
-    ip_ref,     # (bq, 1)  f32 -- <q, leaf.c> for this tile
-    lb_ref,     # (bq, 1)  f32 -- node-level ball bound for this tile
-    cn_ref,     # (1, 1)   f32 -- ||leaf.c||
-    pts_ref,    # (1, n0, dp) f32 -- the leaf tile's points
-    ids_ref,    # (1, n0) i32 -- global ids (-1 = pad)
-    rx_ref,     # (1, n0) f32 -- ||x - N.c|| descending (Alg. 4 line 9)
-    xc_ref,     # (1, n0) f32 -- ||x|| cos(phi_x)
-    xs_ref,     # (1, n0) f32 -- ||x|| sin(phi_x)
-    # outputs
-    out_d_ref,  # (bq, k) f32
-    out_i_ref,  # (bq, k) i32
-    out_s_ref,  # (1, 1)  i32 -- per-query-block skipped-tile count
-    # scratch
-    topd,       # VMEM (bq, k) f32 -- running top-k distances (unsorted)
-    topi,       # VMEM (bq, k) i32
-    nskip,      # SMEM (1,) i32 -- skipped-tile counter (stats)
-    *,
-    k: int,
-    use_ball: bool,
-    use_cone: bool,
-):
-    del visit_ref  # consumed by the index maps
-    j = pl.program_id(1)
-    n_tiles = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        topd[...] = jnp.full(topd.shape, _NEG_FILL, topd.dtype)
-        topi[...] = jnp.full(topi.shape, -1, topi.dtype)
-        nskip[0] = 0
-
-    # lambda = current k-th best (max over the unsorted top-k), optionally
-    # tightened by the externally supplied cap (two-round distributed mode).
-    lam = jnp.minimum(jnp.max(topd[...], axis=1), cap_ref[..., 0])  # (bq,)
-    active = lb_ref[..., 0] < lam  # Theorem 2 prune, per query
-
-    @pl.when(jnp.logical_not(jnp.any(active)))
-    def _count_skip():
-        nskip[0] = nskip[0] + 1
-
-    @pl.when(jnp.any(active))
-    def _scan_tile():
-        ids = ids_ref[0]          # (n0,)
-        keep = (ids >= 0)[None, :] & active[:, None]  # (bq, n0)
-        ip = ip_ref[..., 0]       # (bq,)
-        qn = qn_ref[..., 0]
-        if use_ball:  # Corollary 1 (batch prune: rx sorted descending)
-            pb = jnp.maximum(jnp.abs(ip)[:, None] - qn[:, None] * rx_ref[0][None, :], 0.0)
-            keep &= pb < lam[:, None]
-        if use_cone:  # Theorem 3
-            cn = jnp.maximum(cn_ref[0, 0], 1e-12)
-            qcos = ip / cn
-            qsin = jnp.sqrt(jnp.maximum(qn * qn - qcos * qcos, 0.0))
-            cb = _cone_cases(qcos[:, None], qsin[:, None],
-                             xc_ref[0][None, :], xs_ref[0][None, :])
-            keep &= cb < lam[:, None]
-        # verification matmul on the MXU: (bq, dp) x (dp, n0)
-        absip = jnp.abs(
-            jax.lax.dot_general(
-                q_ref[...], pts_ref[0],
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        )
-        cand = jnp.where(keep, absip, _NEG_FILL)  # (bq, n0)
-
-        n0 = cand.shape[1]
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (cand.shape[0], k), 1)
-        iota_n = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
-
-        def insert(_, carry):
-            td, ti, cd = carry
-            m = jnp.min(cd, axis=1)                       # (bq,)
-            am = jnp.argmin(cd, axis=1).astype(jnp.int32)  # (bq,)
-            wv = jnp.max(td, axis=1)
-            wa = jnp.argmax(td, axis=1).astype(jnp.int32)
-            better = m < wv                               # (bq,)
-            oh_w = iota_k == wa[:, None]                  # (bq, k)
-            oh_c = iota_n == am[:, None]                  # (bq, n0)
-            # gather the winning id via one-hot reduction (TPU-friendly)
-            win_id = jnp.max(jnp.where(oh_c, ids[None, :], -1), axis=1)
-            td = jnp.where(oh_w & better[:, None], m[:, None], td)
-            ti = jnp.where(oh_w & better[:, None], win_id[:, None], ti)
-            cd = jnp.where(oh_c & better[:, None], _NEG_FILL, cd)
-            return td, ti, cd
-
-        td, ti, _ = jax.lax.fori_loop(
-            0, k, insert, (topd[...], topi[...], cand))
-        topd[...] = td
-        topi[...] = ti
-
-    @pl.when(j == n_tiles - 1)
-    def _write_out():
-        out_d_ref[...] = topd[...]
-        out_i_ref[...] = topi[...]
-        out_s_ref[0, 0] = nskip[0]
+__all__ = ["p2h_sweep"]
 
 
 def p2h_sweep(
@@ -184,66 +57,9 @@ def p2h_sweep(
     *block-granularly* (node-level ball bound >= lambda for every query in
     the block -- the ``pl.when`` elision in the kernel).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B, dp = queries.shape
-    L, n0, _ = pts_tiles.shape
-    nqb, n_visit = visit.shape
-    assert B == nqb * bq, (B, nqb, bq)
-
-    grid = (nqb, n_visit)
-
-    def qmap(i, j, v):          # query-block operands
-        del j, v
-        return (i, 0)
-
-    def tmap(i, j, v):          # tile operands gathered via scalar prefetch
-        return (v[i, j], 0)
-
-    def tmap3(i, j, v):
-        return (v[i, j], 0, 0)
-
-    def ipmap(i, j, v):         # (B, L) operands: row block i, col visit[i, j]
-        return (i, v[i, j])
-
-    kernel = functools.partial(
-        p2h_sweep_kernel, k=k, use_ball=use_ball, use_cone=use_cone)
-
-    out_d, out_i, out_s = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bq, dp), qmap),       # queries
-                pl.BlockSpec((bq, 1), qmap),        # qnorm
-                pl.BlockSpec((bq, 1), qmap),        # cap
-                pl.BlockSpec((bq, 1), ipmap),       # leaf_ip
-                pl.BlockSpec((bq, 1), ipmap),       # leaf_lb
-                pl.BlockSpec((1, 1), tmap),         # leaf_cnorm
-                pl.BlockSpec((1, n0, dp), tmap3),   # points
-                pl.BlockSpec((1, n0), tmap),        # ids
-                pl.BlockSpec((1, n0), tmap),        # rx
-                pl.BlockSpec((1, n0), tmap),        # xcos
-                pl.BlockSpec((1, n0), tmap),        # xsin
-            ],
-            out_specs=[
-                pl.BlockSpec((bq, k), qmap),
-                pl.BlockSpec((bq, k), qmap),
-                pl.BlockSpec((1, 1), lambda i, j, v: (i, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bq, k), jnp.float32),
-                pltpu.VMEM((bq, k), jnp.int32),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((nqb, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(visit, queries, qnorm, cap, leaf_ip, leaf_lb, leaf_cnorm,
-      pts_tiles, ids_tiles, rx_tiles, xc_tiles, xs_tiles)
-    return out_d, out_i, out_s
+    tiles = (pts_tiles, ids_tiles, rx_tiles, xc_tiles, xs_tiles, leaf_cnorm)
+    d, i, s = stacked_sweep(
+        *(a[None] for a in tiles), queries, qnorm, cap, leaf_ip[None],
+        leaf_lb[None], visit[None], k=k, bq=bq, use_ball=use_ball,
+        use_cone=use_cone, interpret=interpret)
+    return d[0], i[0], s[0]

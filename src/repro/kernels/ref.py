@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.p2h_scan import _cone_cases
+from repro.core.bounds import EXACT, _cone_cases
 
 __all__ = ["p2h_sweep_ref", "stacked_sweep_ref"]
 
@@ -89,7 +89,8 @@ def p2h_sweep_ref(
                                  xc_tiles[leaf][None, :], xs_tiles[leaf][None, :])
                 keep &= cb < lam[:, None]
             if probe_dtype == "f32":
-                absip = jnp.abs(qb @ pts_tiles[leaf].T)
+                absip = jnp.abs(jnp.dot(qb, pts_tiles[leaf].T,
+                                        precision=EXACT))
                 cand = jnp.where(keep, absip, jnp.inf)
             else:
                 if probe_dtype == "bf16":

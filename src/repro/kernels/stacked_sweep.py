@@ -47,6 +47,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import logging
 import threading
 import weakref
 
@@ -59,8 +60,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as _P
 
 from repro.core import bounds
-from repro.kernels.p2h_scan import _cone_cases
-from repro.parallel.sharding import mesh_signature, shard_map_compat
+from repro.parallel.sharding import mesh_signature
 
 __all__ = ["StackedLeaves", "stacked_sweep", "stacked_sweep_search",
            "stacked_sweep_query", "prepare_stacked_operands",
@@ -68,10 +68,12 @@ __all__ = ["StackedLeaves", "stacked_sweep", "stacked_sweep_search",
            "resolve_probe_dtype", "resolve_stacked_backend",
            "quantization_slack", "probe_bytes_per_tile",
            "warm_stacked", "stacked_compile_stats",
-           "reset_stacked_compile_stats",
+           "reset_stacked_compile_stats", "record_warm_failure",
            "STACKED_FANOUT_DEFAULT", "STACKED_DENSITY_DEFAULT",
            "STACKED_PROBE_TILES_DEFAULT",
            "STACKED_PROBE_TILES_ROUND2_DEFAULT", "PROBE_DTYPES"]
+
+logger = logging.getLogger(__name__)
 
 _LANE = 128
 _NEG_FILL = jnp.inf
@@ -582,7 +584,8 @@ def prepare_stacked_operands(stk: StackedLeaves, queries, *, frac=1.0,
            else jnp.pad(jnp.asarray(lambda_cap, jnp.float32).reshape(B0, 1),
                         ((0, Bp - B0), (0, 0)), constant_values=jnp.inf))
 
-    ipc = jnp.einsum("bd,nld->nbl", q, stk.leaf_centers)  # (N, Bp, L)
+    ipc = jnp.einsum("bd,nld->nbl", q, stk.leaf_centers,
+                     precision=bounds.EXACT)  # (N, Bp, L)
     lb = bounds.node_ball_bound(ipc, qn[None, :, :],
                                 stk.leaf_radii[:, None, :])
     lb = jnp.where(stk.valid[:, None, :], lb, jnp.inf)
@@ -618,11 +621,34 @@ def prepare_stacked_operands(stk: StackedLeaves, queries, *, frac=1.0,
 # the stacked Pallas kernel
 # ======================================================================
 
+#: words of the 1 MiB scalar memory the flattened visit table may take in
+#: one launch.  A larger batch is swept by several launches over whole
+#: query blocks: a block's sweep never spans two launches, so the split
+#: changes no result.  Half the memory, leaving room for the kernel's own
+#: scalars.
+_SMEM_VISIT_WORDS = 128 * 1024
+
+#: rows of the per-step scalar plane after the ``2 * bq`` bound rows: the
+#: tile's ``||c||``, int8 dequant scale and the two quantization-slack
+#: coefficients, padded to a sublane multiple.
+_TILE_SCALAR_ROWS = 8
+
+#: per-point rows of a tile, packed as int32: rx, xcos and xsin bit-cast
+#: from f32, then the ids.  Integer on the way in because small ids
+#: bit-cast to f32 are subnormals, which a TPU flushes to zero.
+_POINT_ROWS = 4
+
+
+def _first_index(mask, iota, fill):
+    """Per-row lane index of the first True of ``mask`` (``fill`` if none):
+    ``argmin``/``argmax`` as a masked min, which Mosaic lowers."""
+    return jnp.min(jnp.where(mask, iota, fill), axis=1, keepdims=True)
+
 
 def stacked_sweep_kernel(
     # scalar prefetch
-    visit_ref,  # (N, nqb, n_visit) i32 -- per-(segment, block) visit order
-    # inputs (blocked)
+    visit_ref,  # SMEM (N * nqb * n_visit,) i32 -- flattened visit order
+    # inputs (blocked; leading grid dims squeezed)
     q_ref,      # (bq, dp) -- query block (f32; bf16/int8 when the probe
     #              pass scores quantized tiles -- probe_dtype static)
     qn_ref,     # (bq, 1)  f32 -- ||q||
@@ -630,26 +656,20 @@ def stacked_sweep_kernel(
     #              (dequant + slack operand; zeros for f32/bf16)
     cap_ref,    # (bq, 1)  f32 -- the single entry cap (delta k-th /
     #                             cache cap / exchange lambda0)
-    gs_ref,     # (bq, k)  f32 -- global top-k *value* seed (pass B gets
-    #                             pass A's merged planes; +inf cold)
-    sd_ref,     # (1, bq, k) f32 -- seed top-k (pass A's state; +inf cold)
-    si_ref,     # (1, bq, k) i32
-    ip_ref,     # (1, bq, 1) f32 -- <q, leaf.c> for this tile
-    lb_ref,     # (1, bq, 1) f32 -- node-level ball bound (+inf = pad tile)
-    cn_ref,     # (1, 1, 1)  f32 -- ||leaf.c||
-    pts_ref,    # (1, 1, n0, dp) -- the tile's points (f32, or the
-    #              lane-packed bf16/int8 plane on the quantized probe)
-    ids_ref,    # (1, 1, n0) i32 -- global ids (-1 = pad/tombstone)
-    rx_ref,     # (1, 1, n0) f32
-    xc_ref,     # (1, 1, n0) f32
-    xs_ref,     # (1, 1, n0) f32
-    qs_ref,     # (1, 1, 1)  f32 -- per-tile int8 dequant scale (1.0 pad)
-    sa_ref,     # (1, 1, 1)  f32 -- quantization-slack coefficient (* ||q||)
-    sb_ref,     # (1, 1, 1)  f32 -- quantization-slack coefficient (* sq)
+    gs_ref,     # (bq, k)  f32 -- global top-k *value* seed (+inf cold)
+    sd_ref,     # (bq, k)  f32 -- this segment's seed top-k (+inf cold)
+    si_ref,     # (bq, k)  i32
+    step_ref,   # (R, 128) f32 -- per-step scalars of 128 visit steps:
+    #              rows [0, bq) <q, leaf.c>, [bq, 2bq) node ball bound
+    #              (+inf = pad tile), then ||c||, int8 tile scale,
+    #              slack_a, slack_b of the visited tile
+    pts_ref,    # (n0, dp) -- the tile's points (f32, or the lane-packed
+    #              bf16/int8 plane on the quantized probe)
+    rows_ref,   # (4, n0)  i32 -- rx, xcos, xsin (f32 bits), ids
     # outputs
-    out_d_ref,  # (1, bq, k) f32 -- this segment's top-k (unsorted)
-    out_i_ref,  # (1, bq, k) i32
-    out_s_ref,  # (1, 1, 1)  i32 -- per-(segment, block) skipped-tile count
+    out_d_ref,  # (bq, k)  f32 -- this segment's top-k (unsorted)
+    out_i_ref,  # (bq, k)  i32
+    out_s_ref,  # (1, 128) i32 -- skipped-tile count, lane-broadcast
     # scratch
     topd,       # VMEM (bq, k) f32 -- running per-segment top-k
     topi,       # VMEM (bq, k) i32
@@ -658,15 +678,14 @@ def stacked_sweep_kernel(
     nskip,      # SMEM (1,) i32
     *,
     k: int,
+    bq: int,
     use_ball: bool,
     use_cone: bool,
     probe_dtype: str = "f32",
 ):
     """One grid step = one leaf tile of one segment for one query block.
 
-    Same tile math as :func:`repro.kernels.p2h_scan.p2h_sweep_kernel`;
-    the extra leading (sequential) grid dimension is the segment, and the
-    running top-k scratch re-initializes at each segment's first tile
+    The running top-k scratch re-initializes at each segment's first tile
     from the *seed* planes -- +inf/-1 on a cold start, pass A's
     per-segment state on the two-pass main sweep (so probed tiles are
     never rescanned).
@@ -680,6 +699,12 @@ def stacked_sweep_kernel(
     ``min(entry cap, global running k-th, segment running k-th)``, and
     pass B additionally seeds ``glob`` with pass A's merged probe planes
     -- caps at least as tight as the host-threaded walk's, one launch.
+
+    Every operand block is Mosaic-legal: its last two dims are whole
+    array dims or (8, 128) multiples.  Per-step scalars therefore arrive
+    as one lane of a visit-ordered ``(R, 128)`` block (re-fetched once
+    per 128 steps) instead of ``(bq, 1)`` column blocks, and the tile's
+    per-point rows as one ``(4, n0)`` block.
     """
     del visit_ref  # consumed by the index maps
     s = pl.program_id(0)
@@ -693,90 +718,84 @@ def stacked_sweep_kernel(
 
     @pl.when(j == 0)
     def _init():  # fresh segment (or query block): resume from the seed
-        topd[...] = sd_ref[0]
-        topi[...] = si_ref[0]
+        topd[...] = sd_ref[...]
+        topi[...] = si_ref[...]
         nskip[0] = 0
 
-    gmax = jnp.max(glob[pl.ds(i, 1)][0], axis=1)  # (bq,) global k-th
-    lam = jnp.minimum(jnp.minimum(jnp.max(topd[...], axis=1), gmax),
-                      cap_ref[..., 0])  # (bq,)
-    active = lb_ref[0, :, 0] < lam  # Theorem 2 prune (pad tiles: lb=+inf)
+    blk = step_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    col = jnp.max(jnp.where(lane == j % _LANE, blk, -jnp.inf), axis=1,
+                  keepdims=True)  # (R, 1): this step's scalars
+    ip = col[:bq]                 # (bq, 1)
+    lb = col[bq:2 * bq]
+    cn, ts, sa, sb = (col[2 * bq + r:2 * bq + r + 1] for r in range(4))
 
-    @pl.when(jnp.logical_not(jnp.any(active)))
+    gmax = jnp.max(glob[pl.ds(i, 1)][0], axis=1, keepdims=True)  # (bq, 1)
+    lam = jnp.minimum(jnp.minimum(jnp.max(topd[...], axis=1, keepdims=True),
+                                  gmax), cap_ref[...])  # (bq, 1)
+    active = lb < lam  # Theorem 2 prune (pad tiles: lb=+inf)
+    any_active = jnp.max(jnp.where(active, 1.0, 0.0)) > 0.0
+
+    @pl.when(jnp.logical_not(any_active))
     def _count_skip():
         nskip[0] = nskip[0] + 1
 
-    @pl.when(jnp.any(active))
+    @pl.when(any_active)
     def _scan_tile():
-        ids = ids_ref[0, 0]       # (n0,)
-        keep = (ids >= 0)[None, :] & active[:, None]  # (bq, n0)
-        ip = ip_ref[0, :, 0]      # (bq,)
-        qn = qn_ref[..., 0]
+        rows = rows_ref[...]
+        rx, xc, xs = (jax.lax.bitcast_convert_type(rows[r:r + 1],
+                                                   jnp.float32)
+                      for r in range(3))  # (1, n0) each
+        ids = rows[3:4]
+        keep = (ids >= 0) & active  # (bq, n0)
+        qn = qn_ref[...]
         if use_ball:  # Corollary 1 (rx sorted descending within the tile)
-            pb = jnp.maximum(
-                jnp.abs(ip)[:, None] - qn[:, None] * rx_ref[0, 0][None, :],
-                0.0)
-            keep &= pb < lam[:, None]
+            pb = jnp.maximum(jnp.abs(ip) - qn * rx, 0.0)
+            keep &= pb < lam
         if use_cone:  # Theorem 3
-            cn = jnp.maximum(cn_ref[0, 0, 0], 1e-12)
-            qcos = ip / cn
+            qcos = ip / jnp.maximum(cn, 1e-12)
             qsin = jnp.sqrt(jnp.maximum(qn * qn - qcos * qcos, 0.0))
-            cb = _cone_cases(qcos[:, None], qsin[:, None],
-                             xc_ref[0, 0][None, :], xs_ref[0, 0][None, :])
-            keep &= cb < lam[:, None]
+            cb = bounds._cone_cases(qcos, qsin, xc, xs)
+            keep &= cb < lam
         # scoring matmul on the MXU: (bq, dp) x (dp, n0).  Quantized
         # probe modes dequantize + widen here, *inside* the pl.when
         # gate, so pad / all-tombstone tiles (lb = +inf -> never active)
         # are force-skipped before any dequantization arithmetic runs --
         # a degenerate scale can never leak NaN/inf into live scores.
+        raw = jax.lax.dot_general(
+            q_ref[...], pts_ref[...],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=bounds.EXACT if probe_dtype == "f32" else None,
+            preferred_element_type=(jnp.int32 if probe_dtype == "int8"
+                                    else jnp.float32))
         if probe_dtype == "f32":
-            absip = jnp.abs(
-                jax.lax.dot_general(
-                    q_ref[...], pts_ref[0, 0],
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-            cand = jnp.where(keep, absip, _NEG_FILL)  # (bq, n0)
+            cand = jnp.where(keep, jnp.abs(raw), _NEG_FILL)  # (bq, n0)
         else:
-            if probe_dtype == "bf16":
-                raw = jax.lax.dot_general(
-                    q_ref[...], pts_ref[0, 0],
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            else:  # int8 x int8 -> exact int32 accumulation, then
+            if probe_dtype == "int8":  # exact int32 accumulation, then
                 #    dequantize by (query scale * tile scale)
-                acc = jax.lax.dot_general(
-                    q_ref[...], pts_ref[0, 0],
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                )
-                raw = (acc.astype(jnp.float32)
-                       * (sq_ref[..., 0][:, None] * qs_ref[0, 0, 0]))
+                raw = raw.astype(jnp.float32) * (sq_ref[...] * ts)
             # widen by the conservative quantization slack: every
             # candidate value stays >= its true distance, so the merged
             # probe k-th stays a valid global cap (quantization_slack)
-            err = (qn_ref[..., 0] * sa_ref[0, 0, 0]
-                   + sq_ref[..., 0] * sb_ref[0, 0, 0])  # (bq,)
-            cand = jnp.where(keep, jnp.abs(raw) + err[:, None], _NEG_FILL)
+            err = qn * sa + sq_ref[...] * sb  # (bq, 1)
+            cand = jnp.where(keep, jnp.abs(raw) + err, _NEG_FILL)
 
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (cand.shape[0], k), 1)
+        iota_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
         iota_n = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
 
         def insert(_, carry):
             td, ti, cd = carry
-            m = jnp.min(cd, axis=1)
-            am = jnp.argmin(cd, axis=1).astype(jnp.int32)
-            wv = jnp.max(td, axis=1)
-            wa = jnp.argmax(td, axis=1).astype(jnp.int32)
+            m = jnp.min(cd, axis=1, keepdims=True)
+            am = _first_index(cd == m, iota_n, cd.shape[1])
+            wv = jnp.max(td, axis=1, keepdims=True)
+            wa = _first_index(td == wv, iota_k, k)
             better = m < wv
-            oh_w = iota_k == wa[:, None]
-            oh_c = iota_n == am[:, None]
-            win_id = jnp.max(jnp.where(oh_c, ids[None, :], -1), axis=1)
-            td = jnp.where(oh_w & better[:, None], m[:, None], td)
-            ti = jnp.where(oh_w & better[:, None], win_id[:, None], ti)
-            cd = jnp.where(oh_c & better[:, None], _NEG_FILL, cd)
+            oh_w = (iota_k == wa) & better
+            oh_c = iota_n == am
+            win_id = jnp.max(jnp.where(oh_c, ids, -1), axis=1, keepdims=True)
+            td = jnp.where(oh_w, m, td)
+            ti = jnp.where(oh_w, win_id, ti)
+            cd = jnp.where(oh_c & better, _NEG_FILL, cd)
             return td, ti, cd
 
         td, ti, _ = jax.lax.fori_loop(
@@ -786,29 +805,27 @@ def stacked_sweep_kernel(
 
     @pl.when(j == n_tiles - 1)
     def _write_out():
-        out_d_ref[0] = topd[...]
-        out_i_ref[0] = topi[...]
-        out_s_ref[0, 0, 0] = nskip[0]
+        out_d_ref[...] = topd[...]
+        out_i_ref[...] = topi[...]
+        out_s_ref[...] = jnp.full(out_s_ref.shape, nskip[0], jnp.int32)
         # fold this segment's top-k values into the per-block global
         # running state (k-smallest of the 2k values; same insertion
         # pattern as the tile scan, values only -- ids stay per-segment)
-        g0 = glob[pl.ds(i, 1)][0]
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, g0.shape, 1)
+        iota_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
 
         def fold(_, carry):
             g, cd = carry
-            m = jnp.min(cd, axis=1)
-            am = jnp.argmin(cd, axis=1).astype(jnp.int32)
-            wv = jnp.max(g, axis=1)
-            wa = jnp.argmax(g, axis=1).astype(jnp.int32)
+            m = jnp.min(cd, axis=1, keepdims=True)
+            am = _first_index(cd == m, iota_k, k)
+            wv = jnp.max(g, axis=1, keepdims=True)
+            wa = _first_index(g == wv, iota_k, k)
             better = m < wv
-            oh_w = iota_k == wa[:, None]
-            oh_c = iota_k == am[:, None]
-            g = jnp.where(oh_w & better[:, None], m[:, None], g)
-            cd = jnp.where(oh_c & better[:, None], _NEG_FILL, cd)
+            g = jnp.where((iota_k == wa) & better, m, g)
+            cd = jnp.where((iota_k == am) & better, _NEG_FILL, cd)
             return g, cd
 
-        g, _ = jax.lax.fori_loop(0, k, fold, (g0, topd[...]))
+        g, _ = jax.lax.fori_loop(
+            0, k, fold, (glob[pl.ds(i, 1)][0], topd[...]))
         glob[pl.ds(i, 1)] = g[None]
 
 
@@ -830,6 +847,50 @@ def resolve_stacked_backend(use_kernel: bool | None,
     if use_kernel and backend == "gpu":
         interpret = True  # TPU-shaped Pallas grid: no Triton lowering
     return bool(use_kernel), bool(interpret)
+
+
+def _step_scalars(leaf_ip, leaf_lb, leaf_cnorm, tile_scale, slack_a,
+                  slack_b, visit, bq):
+    """The kernel's per-step scalars in visit order, lane-packed:
+    ``(N, nqb, ceil(n_visit / 128), R, 128)`` f32 with ``R = 2 * bq +
+    8``.  Step ``j`` of block ``i`` in segment ``s`` is lane ``j % 128`` of
+    block ``[s, i, j // 128]``: rows ``[0, bq)`` hold ``<q, leaf.c>``,
+    ``[bq, 2 bq)`` the node ball bound, then the tile's ``||c||``, int8
+    scale and slack coefficients (rows left over are zero)."""
+    N, B, L = leaf_ip.shape
+    _, nqb, nv = visit.shape
+
+    def per_query(a):  # (N, B, L) -> (N, nqb, bq, nv)
+        return jnp.take_along_axis(a.reshape(N, nqb, bq, L),
+                                   visit[:, :, None, :], axis=3)
+
+    seg = jnp.arange(N)[:, None, None]
+
+    def per_tile(a):  # (N, L, 1) -> (N, nqb, 1, nv)
+        return a[..., 0][seg, visit][:, :, None, :]
+
+    planes = jnp.concatenate(
+        [per_query(leaf_ip), per_query(leaf_lb), per_tile(leaf_cnorm),
+         per_tile(tile_scale), per_tile(slack_a), per_tile(slack_b),
+         jnp.zeros((N, nqb, _TILE_SCALAR_ROWS - 4, nv), jnp.float32)],
+        axis=2)
+    nvp = _ceil_to(nv, _LANE)
+    planes = jnp.pad(planes, ((0, 0), (0, 0), (0, 0), (0, nvp - nv)))
+    R = planes.shape[2]
+    return planes.reshape(N, nqb, R, nvp // _LANE, _LANE).transpose(
+        0, 1, 3, 2, 4)
+
+
+def _launch_blocks(nqb: int, per_block: int) -> int:
+    """Query blocks per launch: as many as keep the flattened visit table
+    within :data:`_SMEM_VISIT_WORDS`, balanced over the launches."""
+    if per_block > _SMEM_VISIT_WORDS:
+        raise ValueError(
+            f"one query block's visit table ({per_block} words) exceeds "
+            f"the {_SMEM_VISIT_WORDS}-word scalar-memory budget")
+    g = max(1, min(nqb, _SMEM_VISIT_WORDS // per_block))
+    n_launch = -(-nqb // g)
+    return -(-nqb // n_launch)
 
 
 def stacked_sweep(
@@ -877,11 +938,15 @@ def stacked_sweep(
     :func:`quantization_slack` term in-kernel, and the returned ``dists``
     are *widened upper bounds* (valid pruning state, not exact answers
     -- the caller's f32 main pass rescans).
+
+    The operands are re-laid out for Mosaic here (per-step scalars in
+    visit order, per-point rows packed per tile) and a batch whose visit
+    table outgrows scalar memory runs as several launches.
     """
     _, interpret = resolve_stacked_backend(True, interpret)
     B, dp = queries.shape
     N, L, n0, _ = pts_tiles.shape
-    _, nqb, n_visit = visit.shape
+    _, nqb, nv = visit.shape
     assert B == nqb * bq, (B, nqb, bq)
     assert visit.shape[0] == N, (visit.shape, N)
     if seed_d is None:
@@ -898,76 +963,76 @@ def stacked_sweep(
     if slack_b is None:
         slack_b = jnp.zeros((N, L, 1), jnp.float32)
 
-    grid = (N, nqb, n_visit)
-
-    def qmap(s, i, j, v):        # query-block operands (segment-invariant)
-        del s, j, v
-        return (i, 0)
-
-    def tmap(s, i, j, v):        # tile operands gathered via prefetch
-        return (s, v[s, i, j], 0)
-
-    def tmap4(s, i, j, v):
-        return (s, v[s, i, j], 0, 0)
-
-    def ipmap(s, i, j, v):       # (N, B, L): segment s, row block i,
-        return (s, i, v[s, i, j])  # col = j-th preferred tile
-
-    def omap(s, i, j, v):
-        del j, v
-        return (s, i, 0)
-
+    step = _step_scalars(leaf_ip, leaf_lb, leaf_cnorm, tile_scale, slack_a,
+                         slack_b, visit, bq)
+    R = step.shape[3]
+    rows = jnp.stack(
+        [jax.lax.bitcast_convert_type(a, jnp.int32)
+         for a in (rx_tiles, xc_tiles, xs_tiles)] + [ids_tiles],
+        axis=2)  # (N, L, 4, n0) i32
     kernel = functools.partial(
-        stacked_sweep_kernel, k=k, use_ball=use_ball, use_cone=use_cone,
-        probe_dtype=probe_dtype)
+        stacked_sweep_kernel, k=k, bq=bq, use_ball=use_ball,
+        use_cone=use_cone, probe_dtype=probe_dtype)
 
-    out_d, out_i, out_s = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bq, dp), qmap),       # queries
-                pl.BlockSpec((bq, 1), qmap),        # qnorm
-                pl.BlockSpec((bq, 1), qmap),        # sq (query scale)
-                pl.BlockSpec((bq, 1), qmap),        # cap
-                pl.BlockSpec((bq, k), qmap),        # global value seed
-                pl.BlockSpec((1, bq, k), omap),     # seed top-k dists
-                pl.BlockSpec((1, bq, k), omap),     # seed top-k ids
-                pl.BlockSpec((1, bq, 1), ipmap),    # leaf_ip
-                pl.BlockSpec((1, bq, 1), ipmap),    # leaf_lb
-                pl.BlockSpec((1, 1, 1), tmap),      # leaf_cnorm
-                pl.BlockSpec((1, 1, n0, dp), tmap4),  # points
-                pl.BlockSpec((1, 1, n0), tmap),     # ids
-                pl.BlockSpec((1, 1, n0), tmap),     # rx
-                pl.BlockSpec((1, 1, n0), tmap),     # xcos
-                pl.BlockSpec((1, 1, n0), tmap),     # xsin
-                pl.BlockSpec((1, 1, 1), tmap),      # tile scale
-                pl.BlockSpec((1, 1, 1), tmap),      # slack_a
-                pl.BlockSpec((1, 1, 1), tmap),      # slack_b
+    def launch(c0, g):  # query blocks [c0, c0 + g)
+        b0, b1 = c0 * bq, (c0 + g) * bq
+
+        def qmap(s, i, j, v):      # query-block operands
+            return (i, 0)
+
+        def omap(s, i, j, v):      # per-(segment, query-block) planes
+            return (s, i, 0)
+
+        def smap(s, i, j, v):      # 128 visit steps per block
+            return (s, i, j // _LANE, 0, 0)
+
+        def tmap(s, i, j, v):      # the j-th preferred tile
+            return (s, v[(s * g + i) * nv + j], 0, 0)
+
+        out_d, out_i, out_s = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(N, g, nv),
+                in_specs=[
+                    pl.BlockSpec((bq, dp), qmap),            # queries
+                    pl.BlockSpec((bq, 1), qmap),             # qnorm
+                    pl.BlockSpec((bq, 1), qmap),             # sq
+                    pl.BlockSpec((bq, 1), qmap),             # cap
+                    pl.BlockSpec((bq, k), qmap),             # global seed
+                    pl.BlockSpec((None, bq, k), omap),       # seed dists
+                    pl.BlockSpec((None, bq, k), omap),       # seed ids
+                    pl.BlockSpec((None, None, None, R, _LANE), smap),
+                    pl.BlockSpec((None, None, n0, dp), tmap),  # points
+                    pl.BlockSpec((None, None, _POINT_ROWS, n0), tmap),
+                ],
+                out_specs=[
+                    pl.BlockSpec((None, bq, k), omap),
+                    pl.BlockSpec((None, bq, k), omap),
+                    pl.BlockSpec((None, None, 1, _LANE),
+                                 lambda s, i, j, v: (s, i, 0, 0)),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((bq, k), jnp.float32),
+                    pltpu.VMEM((bq, k), jnp.int32),
+                    pltpu.VMEM((g, bq, k), jnp.float32),  # global top-k
+                    pltpu.SMEM((1,), jnp.int32),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((N, g * bq, k), jnp.float32),
+                jax.ShapeDtypeStruct((N, g * bq, k), jnp.int32),
+                jax.ShapeDtypeStruct((N, g, 1, _LANE), jnp.int32),
             ],
-            out_specs=[
-                pl.BlockSpec((1, bq, k), omap),
-                pl.BlockSpec((1, bq, k), omap),
-                pl.BlockSpec((1, 1, 1), omap),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bq, k), jnp.float32),
-                pltpu.VMEM((bq, k), jnp.int32),
-                pltpu.VMEM((nqb, bq, k), jnp.float32),  # global top-k
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((N, B, k), jnp.float32),
-            jax.ShapeDtypeStruct((N, B, k), jnp.int32),
-            jax.ShapeDtypeStruct((N, nqb, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(visit, queries, qnorm, sq, cap, global_seed, seed_d, seed_i,
-      leaf_ip, leaf_lb, leaf_cnorm, pts_tiles, ids_tiles, rx_tiles,
-      xc_tiles, xs_tiles, tile_scale, slack_a, slack_b)
-    return out_d, out_i, out_s
+            interpret=interpret,
+        )(visit[:, c0:c0 + g].reshape(-1), queries[b0:b1], qnorm[b0:b1],
+          sq[b0:b1], cap[b0:b1], global_seed[b0:b1], seed_d[:, b0:b1],
+          seed_i[:, b0:b1], step[:, c0:c0 + g], pts_tiles, rows)
+        return out_d, out_i, out_s[:, :, :, 0]
+
+    g = _launch_blocks(nqb, N * nv)
+    outs = [launch(c0, min(g, nqb - c0)) for c0 in range(0, nqb, g)]
+    return tuple(jnp.concatenate(parts, axis=1) for parts in zip(*outs))
 
 
 # ======================================================================
@@ -1303,10 +1368,12 @@ def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
         return gather(bd_l), gather(bi_l), gather(sk_l), gather(psk_l)
 
     in_spec = jax.tree.map(lambda _: _P(mesh_axis), arrays)
-    bd, bi, skips, probe_sk = shard_map_compat(
+    # replication checking off: the gathered outputs are replicated by
+    # construction, which the static checker cannot prove
+    bd, bi, skips, probe_sk = jax.shard_map(
         local, mesh=mesh,
         in_specs=(in_spec, _P(), _P(), _P()),
-        out_specs=(_P(), _P(), _P(), _P()),
+        out_specs=(_P(), _P(), _P(), _P()), check_vma=False,
     )(arrays, queries, cap0, gseed)
     true_row = jnp.arange(bd.shape[0]) < n_true
     probe_skips = (jnp.sum(jnp.where(true_row[:, None, None],
@@ -1497,7 +1564,7 @@ def _placed_arrays(stk: StackedLeaves, arrays: dict, Np: int, mesh,
 _COMPILE_LOCK = threading.Lock()
 _COMPILE_SIGS: "dict[tuple, int]" = {}
 _COMPILE_STATS = {"misses": 0, "hits": 0,
-                  "warm_compiles": 0, "warm_hits": 0}
+                  "warm_compiles": 0, "warm_hits": 0, "warm_failures": 0}
 _RECENT_TEMPLATES: "collections.OrderedDict[tuple, bool]" = \
     collections.OrderedDict()
 _RECENT_TEMPLATES_SIZE = 16
@@ -1505,6 +1572,19 @@ _RECENT_TEMPLATES_SIZE = 16
 # when the timed-window miss counter is nonzero and you need to know
 # *which* shape slipped past the warmup
 _RECENT_MISSES: "collections.deque[tuple]" = collections.deque(maxlen=8)
+_RECENT_WARM_ERRORS: "collections.deque[str]" = collections.deque(maxlen=4)
+
+
+def record_warm_failure(where: str, exc: BaseException) -> None:
+    """Count and log one warm-up that raised.  A warm-up never breaks a
+    publish, but its failure means the first query on the new state
+    compiles on path -- or, on a chip, that the program does not build
+    at all -- so every such failure shows in ``warm_failures`` of
+    :func:`stacked_compile_stats` and of the engine's ``stats()``."""
+    with _COMPILE_LOCK:
+        _COMPILE_STATS["warm_failures"] += 1
+        _RECENT_WARM_ERRORS.append(f"{where}: {exc!r}")
+    logger.warning("warm-up failed in %s: %r", where, exc, exc_info=exc)
 
 
 def _record_sig(sig: tuple, template: tuple, warm: bool) -> bool:
@@ -1529,7 +1609,9 @@ def _record_sig(sig: tuple, template: tuple, warm: bool) -> bool:
 def stacked_compile_stats() -> dict:
     """Registry counters: ``misses``/``hits`` (serving dispatches that
     did / did not need a fresh trace), ``warm_compiles``/``warm_hits``
-    (same, for :func:`warm_stacked` replays), plus the bench-facing
+    (same, for :func:`warm_stacked` replays), ``warm_failures`` and
+    ``recent_warm_errors`` (:func:`record_warm_failure`), plus the
+    bench-facing
     aliases ``compile_count`` (all fresh traces, warm included -- warm
     ones are *off* the query path, which is the point) and ``cache_hit``
     (serving hits)."""
@@ -1537,6 +1619,7 @@ def stacked_compile_stats() -> dict:
         st = dict(_COMPILE_STATS)
         st["signatures"] = len(_COMPILE_SIGS)
         st["recent_misses"] = list(_RECENT_MISSES)
+        st["recent_warm_errors"] = list(_RECENT_WARM_ERRORS)
     st["compile_count"] = st["misses"] + st["warm_compiles"]
     st["cache_hit"] = st["hits"]
     return st
@@ -1549,6 +1632,7 @@ def reset_stacked_compile_stats(full: bool = False) -> None:
         for key in _COMPILE_STATS:
             _COMPILE_STATS[key] = 0
         _RECENT_MISSES.clear()
+        _RECENT_WARM_ERRORS.clear()
         if full:
             _COMPILE_SIGS.clear()
             _RECENT_TEMPLATES.clear()
@@ -1672,8 +1756,8 @@ def warm_stacked(stk: StackedLeaves, templates=None) -> int:
                 sort_planes=sort_planes, mesh=mesh, mesh_axis=mesh_axis,
                 _warm=True)
             n += 1
-        except Exception:  # warmup must never break a publish
-            continue
+        except Exception as e:  # warmup must never break a publish
+            record_warm_failure("warm_stacked", e)
     return n
 
 

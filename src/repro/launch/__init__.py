@@ -2,7 +2,8 @@
 entry points, platform/backend selection."""
 
 from repro.launch.platform import (GPU_XLA_FLAGS, platform_diagnostics,
-                                   set_host_cpu_devices, set_platform)
+                                   set_host_cpu_devices, set_platform,
+                                   use_compile_cache)
 
 __all__ = ["GPU_XLA_FLAGS", "platform_diagnostics",
-           "set_host_cpu_devices", "set_platform"]
+           "set_host_cpu_devices", "set_platform", "use_compile_cache"]

@@ -192,9 +192,7 @@ def _lower_cell(arch, shape_id, mesh, cfg, *, donate=True):
         blog[k], specs[k].shape, mesh, rules=cfg.rules, name=k))
         for k in specs}
 
-    from repro.launch.mesh import mesh_context
-
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             from repro.optim.schedule import cosine_schedule
             step = make_train_step(

@@ -9,43 +9,15 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh", "mesh_context", "make_production_mesh",
-           "make_test_mesh", "make_serving_mesh"]
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    Newer jax: ``jax.set_mesh`` (sharding-in-types mesh context).  Older
-    jax has no ``set_mesh``; ``Mesh`` itself is the ambient-mesh context
-    manager there (and ``repro.parallel.shard`` already degrades to a
-    no-op when the new ambient-mesh API is absent).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+__all__ = ["make_mesh", "make_production_mesh", "make_test_mesh",
+           "make_serving_mesh"]
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) only exist in
-    newer jax releases; older ones default every axis to the same
-    auto-partitioning behavior, so omitting the kwarg is equivalent.  On
-    releases predating ``jax.make_mesh`` itself, fall back to building the
-    ``Mesh`` from ``mesh_utils.create_device_mesh``.
-    """
-    jmm = getattr(jax, "make_mesh", None)
-    if jmm is None:  # very old jax
-        from jax.experimental import mesh_utils
-
-        devices = mesh_utils.create_device_mesh(tuple(shape))
-        return jax.sharding.Mesh(devices, tuple(axes))
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jmm(shape, axes)
-    return jmm(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis ``Auto``: the compiler partitions
+    whatever the programs do not place explicitly."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

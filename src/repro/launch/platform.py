@@ -15,7 +15,9 @@ where intended:
 * :func:`set_host_cpu_devices` -- fabricate N host CPU devices (the CI
   mesh lane's 4-device topology on GPU-less runners);
 * :func:`platform_diagnostics` -- what a bug report needs: resolved
-  backend, device inventory, and how the stacked sweep will route.
+  backend, device inventory, and how the stacked sweep will route;
+* :func:`use_compile_cache` -- the persistent compilation cache of the
+  entry points (``chip_smoke.py``, ``benchmarks/run.py``).
 
 Flag edits only take effect before JAX initializes its backends; both
 setters therefore *merge* into ``XLA_FLAGS`` (never clobber -- a user's
@@ -26,11 +28,18 @@ from __future__ import annotations
 
 import os
 import warnings
+from pathlib import Path
 
 import jax
 
 __all__ = ["set_platform", "set_host_cpu_devices", "platform_diagnostics",
-           "GPU_XLA_FLAGS"]
+           "use_compile_cache", "GPU_XLA_FLAGS"]
+
+#: the compile cache's fixed home when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: inside the checkout (ignored by git), so every run of the same
+#: checkout finds the programs the previous one compiled -- the path is
+#: part of the cache key, so it must not move between runs.
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 #: the XLA:GPU serving recipe (jax.readthedocs.io gpu_performance_tips):
 #: async collectives + latency-hiding scheduling overlap the mesh path's
@@ -48,11 +57,9 @@ GPU_XLA_FLAGS = (
 def _backends_initialized() -> bool:
     """Whether JAX has already committed to its backends (flag edits
     after this point silently do nothing)."""
-    try:
-        return bool(
-            jax._src.xla_bridge._backends)  # type: ignore[attr-defined]
-    except AttributeError:  # private API moved: assume the worst
-        return True
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
 def _merge_xla_flags(flags) -> None:
@@ -121,3 +128,15 @@ def platform_diagnostics() -> dict:
         "interpret": interpret,
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
     }
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it
+    itself, so nothing else is set here), else the checkout's
+    ``.jax_cache/``.  Call before the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -15,7 +15,6 @@ single device, so the same model code runs in CPU smoke tests and in the
 """
 from __future__ import annotations
 
-import functools
 import logging
 import threading
 from collections import deque
@@ -29,7 +28,7 @@ logger = logging.getLogger(__name__)
 
 __all__ = [
     "RULES", "shard", "logical_to_spec", "resolve_param_specs", "pad_vocab",
-    "fallback_log", "mesh_signature", "shard_map_compat",
+    "fallback_log", "mesh_signature",
 ]
 
 # logical axis -> mesh axis (or tuple of mesh axes). ``None`` = replicated.
@@ -120,23 +119,6 @@ def mesh_signature(mesh=None) -> tuple:
             devs, getattr(mesh.devices.flat[0], "platform", "?"))
 
 
-def _resolve_shard_map():
-    """``jax.shard_map`` across jax versions (new api vs
-    ``jax.experimental.shard_map``), with replication checking relaxed
-    -- the serving programs produce deterministically-replicated
-    outputs that the static checker cannot always prove."""
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _xsm
-    return functools.partial(_xsm, check_rep=False)
-
-
-def shard_map_compat(fn, **kw):
-    """Version-portable ``shard_map(fn, mesh=..., in_specs=...,
-    out_specs=...)`` (see :func:`_resolve_shard_map`)."""
-    return _resolve_shard_map()(fn, **kw)
-
-
 def _mesh_axis_size(mesh, axes) -> int:
     if axes is None:
         return 1
@@ -208,13 +190,8 @@ def logical_to_spec(
 
 
 def _ambient_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - old jax
-        return None
-    if m is None or getattr(m, "empty", True):
-        return None
-    return m
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def shard(x, *logical: str | None, rules: Mapping[str, Any] | None = None):

@@ -453,6 +453,11 @@ class P2HEngine:
             out["router_transitions"] = self._router_transitions
         if self._mesh_devices > 1:
             out["mesh_devices"] = self._mesh_devices
+        # process-wide: warm-ups that raised (pre-publish, post-publish,
+        # round-1 and template replays) -- nonzero means some program was
+        # left to compile on the query path, or did not build at all
+        from repro.kernels.stacked_sweep import stacked_compile_stats
+        out["warm_failures"] = stacked_compile_stats()["warm_failures"]
         admission = getattr(self.mutable, "admission_stats", None)
         if callable(admission):
             # write-admission counters (seals/stalls/pending) from the

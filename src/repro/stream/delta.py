@@ -24,12 +24,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.bounds import EXACT
+
 __all__ = ["DeltaBuffer", "delta_topk"]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _delta_topk(points, gids, queries, k: int):
-    d = jnp.abs(queries @ points.T)  # (B, C)
+    d = jnp.abs(jnp.dot(queries, points.T, precision=EXACT))  # (B, C)
     d = jnp.where(gids[None, :] >= 0, d, jnp.inf)
     if k > d.shape[1]:  # capacity smaller than k: pad with invalid slots
         pad = k - d.shape[1]

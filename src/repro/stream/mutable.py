@@ -632,8 +632,10 @@ class MutableP2HIndex:
                         and prepub.get("stacked") is not None:
                     try:
                         hook(prepub["stacked"])
-                    except Exception:
-                        pass
+                    except Exception as e:
+                        from repro.kernels.stacked_sweep import \
+                            record_warm_failure
+                        record_warm_failure("post-publish warm-up hook", e)
             except BaseException as e:
                 # never die wedged: writers blocked on _compacting would
                 # hang forever.  Pinned buffers stay in _sealed (still
@@ -725,12 +727,12 @@ class MutableP2HIndex:
         mutates the segment *set* while ``_compacting`` is held (deletes
         only replace objects), so the prediction can only go stale in
         ways :meth:`Snapshot.adopt_prebuilt_stacked` re-diffs.
-        Best-effort: any failure just means the first post-publish query
-        pays the compile, as before."""
+        Best-effort: a failure is counted (``record_warm_failure``) and
+        the first post-publish query pays the compile."""
+        from repro.kernels.stacked_sweep import (StackedLeaves,
+                                                 record_warm_failure,
+                                                 warm_stacked)
         try:
-            from repro.kernels.stacked_sweep import (StackedLeaves,
-                                                     warm_stacked)
-
             plan: CompactionPlan = pin["plan"]
             with self._lock:
                 segs = [seg for uid, seg in self._segments.items()
@@ -755,8 +757,8 @@ class MutableP2HIndex:
                     try:
                         hook(stk)
                         prepub["warmed"] += 1
-                    except Exception:
-                        pass
+                    except Exception as e:
+                        record_warm_failure("pre-publish warm-up hook", e)
             if built is not None:
                 # the exchange's round 1 beams each segment tree with its
                 # own shape-keyed program; warm it for the new tree too,
@@ -769,8 +771,9 @@ class MutableP2HIndex:
                     int(built.gids[local]): ("seg", built.uid, int(local))
                     for local in pid[pid >= 0]}
             return prepub
-        except Exception:
-            return None  # warmup must never break the compaction
+        except Exception as e:  # warmup must never break the compaction
+            record_warm_failure("pre-publish warm-up", e)
+            return None
 
     def _publish_compaction_locked(self, pin: dict,
                                    built: Segment | None,

@@ -36,19 +36,22 @@ skip_without_hypothesis = pytest.mark.skipif(
 
 
 def given_int_seed(*, max_examples: int, hi: int, lo: int = 0,
-                   fallback_seeds=(0, 1, 2)):
+                   fallback_seeds=(0, 1, 2), examples=()):
     """``@given(st.integers(lo, hi))`` for single-seed property tests.
 
-    With hypothesis installed this is the real property test; without it
-    the test degrades to a fixed-seed parametrization so the property
+    With hypothesis installed this is the real property test, and every
+    seed in ``examples`` runs on each call besides the drawn ones; without
+    it the test degrades to a fixed-seed parametrization so the property
     keeps (reduced) coverage instead of being skipped.
     """
 
     def deco(fn):
         if HAVE_HYPOTHESIS:
+            test = hypothesis.given(st.integers(lo, hi))(fn)
+            for seed in examples:
+                test = hypothesis.example(seed)(test)
             return hypothesis.settings(max_examples=max_examples,
-                                       deadline=None)(
-                hypothesis.given(st.integers(lo, hi))(fn))
+                                       deadline=None)(test)
         return pytest.mark.parametrize("seed", list(fallback_seeds))(fn)
 
     return deco

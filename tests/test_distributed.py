@@ -68,7 +68,7 @@ _TRAIN_BODY = textwrap.dedent(
     """
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_model
     from repro.launch.steps import make_train_step, abstract_opt_state
     from repro.optim import adamw_init
@@ -99,10 +99,8 @@ _TRAIN_BODY = textwrap.dedent(
     rep = NamedSharding(mesh, P())
     opt_sh = OptState(mu=param_sh, nu=param_sh, count=rep)
     batch_sh = {k: NamedSharding(mesh, P(("data",), None)) for k in batch}
-    # mesh_context = jax.set_mesh on new jax (activation sharding
-    # constraints active); a benign Mesh context on old jax, where
-    # repro.parallel.shard degrades to a no-op anyway.
-    with mesh_context(mesh):
+    # the ambient mesh makes the activation sharding constraints active
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step, in_shardings=(param_sh, opt_sh, batch_sh))
         p8, o8, m8 = jstep(
             jax.device_put(params, param_sh),

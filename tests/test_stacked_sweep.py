@@ -261,8 +261,9 @@ def _stacked_property(seed):
         else:
             _check_stacked_matches_sequential(m, q, k, f"step{step}")
             checks += 1
-    segs = m.snapshot().segments
-    assert 1 <= len(segs) <= 64
+    # churn may tombstone every segment away (seed 3): an empty segment
+    # list is a legal state, and the final checks must hold there too
+    assert 0 <= len(m.snapshot().segments) <= 64
     for k2 in (1, 5):
         _check_stacked_matches_sequential(m, q, k2, f"final-k{k2}")
     m.compact(force=True)
@@ -270,7 +271,8 @@ def _stacked_property(seed):
 
 
 @pytest.mark.stacked
-@given_int_seed(max_examples=6, hi=2**31 - 1, fallback_seeds=(0, 1, 2))
+@given_int_seed(max_examples=6, hi=2**31 - 1, fallback_seeds=(0, 1, 2, 3),
+                examples=(3,))
 def test_stacked_property_exact_vs_sequential_and_oracle(seed):
     """Acceptance property (stacked lane): random insert / delete /
     whole-segment-tombstone / compaction interleavings leave the stacked
