@@ -61,6 +61,7 @@ from jax.sharding import PartitionSpec as _P
 
 from repro.core import bounds
 from repro.parallel.sharding import mesh_signature
+from repro.runtime import spans
 
 __all__ = ["StackedLeaves", "stacked_sweep", "stacked_sweep_search",
            "stacked_sweep_query", "prepare_stacked_operands",
@@ -1599,6 +1600,7 @@ def _record_sig(sig: tuple, template: tuple, warm: bool) -> bool:
             _COMPILE_STATS["hits" if known else "misses"] += 1
             if not known:
                 _RECENT_MISSES.append(sig)
+                spans.count("stacked_compile_misses")
             _RECENT_TEMPLATES.pop(template, None)
             _RECENT_TEMPLATES[template] = True
             while len(_RECENT_TEMPLATES) > _RECENT_TEMPLATES_SIZE:
@@ -1829,19 +1831,20 @@ def stacked_sweep_query(stk: StackedLeaves, queries, k: int = 1, *,
     launch actually spanned (1 = the single-device program; see
     :func:`_run_stacked_mesh` for the ``mesh=`` form).
     """
-    out, p, pdt = _call_run_stacked(stk, queries, k, frac=frac, bq=bq,
-                                    use_ball=use_ball, use_cone=use_cone,
-                                    lambda_cap=lambda_cap,
-                                    probe_tiles=probe_tiles,
-                                    probe_route=probe_route,
-                                    probe_dtype=probe_dtype,
-                                    extra_d=extra_d, extra_i=extra_i,
-                                    shard_bounds=shard_bounds,
-                                    use_kernel=use_kernel,
-                                    interpret=interpret,
-                                    sort_planes=False,
-                                    mesh=mesh, mesh_axis=mesh_axis)
+    with spans.span("p2h.stacked.launch"):  # host side: returns at dispatch
+        out, p, pdt = _call_run_stacked(
+            stk, queries, k, frac=frac, bq=bq, use_ball=use_ball,
+            use_cone=use_cone, lambda_cap=lambda_cap,
+            probe_tiles=probe_tiles, probe_route=probe_route,
+            probe_dtype=probe_dtype, extra_d=extra_d, extra_i=extra_i,
+            shard_bounds=shard_bounds, use_kernel=use_kernel,
+            interpret=interpret, sort_planes=False, mesh=mesh,
+            mesh_axis=mesh_axis)
     _, _, fd, fi, counters, seg_skips, shard_kth, probe_skips = out
+    # the device time as the host sees it: the launch's own outputs, so
+    # the first blocking read below (probe_skips) waits for nothing more
+    with spans.span("p2h.device_wait"):
+        jax.block_until_ready((fd, fi, counters, probe_skips))
     B = int(np.atleast_2d(np.asarray(queries)).shape[0])
     nqb = -(-B // bq)
     n_visit = _n_visit(stk, frac)

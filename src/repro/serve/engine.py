@@ -23,13 +23,14 @@ calls is an existing jitted backend (``dfs_search``, ``sweep_search``,
 """
 from __future__ import annotations
 
-import time
+import collections
 from typing import Any
 
 import numpy as np
 
 from repro.core import search
 from repro.core.balltree import normalize_query
+from repro.runtime import spans
 from repro.serve.batcher import MicroBatcher
 from repro.serve.dispatch import DispatchPolicy, Route
 from repro.serve.lambda_cache import LambdaCache
@@ -122,7 +123,10 @@ class P2HEngine:
         self._shed = {"queue_full": 0, "deadline": 0, "expired_batches": 0}
         self._route_counts: dict[str, int] = {}
         self._counters: dict[str, np.ndarray] = {}
-        self._latencies_s: list[float] = []
+        # p2h.search durations of this engine's batches, the last
+        # spans.RING of them: latency_p50_ms / latency_p99_ms
+        self._search_s = collections.deque(maxlen=spans.RING)
+        self._batch_seq = 0  # p2h.batch's ``batch`` attr
         self._batches = 0
         self._queries_served = 0
         # placement generation tracking (sharded mutable): every batch
@@ -228,6 +232,11 @@ class P2HEngine:
     # execution
     # ------------------------------------------------------------------
     def _execute(self, mb, *, method: str | None = None):
+        self._batch_seq += 1
+        with spans.span("p2h.batch", batch=self._batch_seq):
+            self._run_batch(mb, method)
+
+    def _run_batch(self, mb, method: str | None):
         deadline = mb.deadline
         if (mb.deadlines and all(d is not None and d.expired
                                  for d in mb.deadlines)):
@@ -255,47 +264,50 @@ class P2HEngine:
             # deadline on an unarmed engine: default supervision, kept
             # so breaker state and counters persist across batches
             self._supervisor = ShardSupervisor()
-        # pin one consistent view for the whole micro-batch: concurrent
-        # inserts/deletes publish new snapshots, this batch never sees them
-        snap = self.mutable.snapshot() if self.mutable is not None else None
-        if snap is not None and self._sharded_mutable:
-            rv = getattr(snap, "router_version", 0)
-            if self._router_version is not None \
-                    and rv != self._router_version:
-                self._router_transitions += 1
-            self._router_version = rv
-        fanout = (len(snap.segments) + len(snap.deltas)) if snap else 1
-        if snap is not None:
-            from repro.kernels.stacked_sweep import tile_density
+        with spans.span("p2h.pin"):
+            # pin one consistent view for the whole micro-batch: concurrent
+            # inserts/deletes publish new snapshots, this batch never sees
+            # them
+            snap = (self.mutable.snapshot() if self.mutable is not None
+                    else None)
+            if snap is not None and self._sharded_mutable:
+                rv = getattr(snap, "router_version", 0)
+                if self._router_version is not None \
+                        and rv != self._router_version:
+                    self._router_transitions += 1
+                self._router_version = rv
+            fanout = (len(snap.segments) + len(snap.deltas)) if snap else 1
+            if snap is not None:
+                from repro.kernels.stacked_sweep import tile_density
 
-            # snapshot-composition signals for the stacked crossover:
-            # live sealed segments (the units one launch can absorb),
-            # live delta rows over live points, dead over sealed rows,
-            # live-tile fraction of the would-be stacked grid
-            stackable = sum(1 for s in snap.segments if s.live)
-            delta_frac = snap.delta_live / max(1, snap.live_count)
-            tombstone_frac = snap.tombstone_frac
-            density = tile_density(snap.segments)
-        else:
-            stackable, delta_frac, tombstone_frac = 0, 0.0, 0.0
-            density = 1.0
-        mesh = getattr(snap, "mesh", None)
-        mesh_devices = (1 if mesh is None
-                        else int(np.asarray(mesh.devices).size))
-        if mesh_devices > 1:
-            self._mesh_devices = mesh_devices
-        route = (Route(method, frac=self.policy.frac_for_recall(
-                     mb.recall_target) if method == "beam" else 1.0,
-                     reason="forced")
-                 if method is not None else
-                 self.policy.route(mb.occupancy, mb.k, mb.recall_target,
-                                   sharded=self.sharded is not None,
-                                   segments=fanout,
-                                   stackable=stackable,
-                                   delta_frac=delta_frac,
-                                   tombstone_frac=tombstone_frac,
-                                   tile_density=density,
-                                   mesh_devices=mesh_devices))
+                # snapshot-composition signals for the stacked crossover:
+                # live sealed segments (the units one launch can absorb),
+                # live delta rows over live points, dead over sealed rows,
+                # live-tile fraction of the would-be stacked grid
+                stackable = sum(1 for s in snap.segments if s.live)
+                delta_frac = snap.delta_live / max(1, snap.live_count)
+                tombstone_frac = snap.tombstone_frac
+                density = tile_density(snap.segments)
+            else:
+                stackable, delta_frac, tombstone_frac = 0, 0.0, 0.0
+                density = 1.0
+            mesh = getattr(snap, "mesh", None)
+            mesh_devices = (1 if mesh is None
+                            else int(np.asarray(mesh.devices).size))
+            if mesh_devices > 1:
+                self._mesh_devices = mesh_devices
+            route = (Route(method, frac=self.policy.frac_for_recall(
+                         mb.recall_target) if method == "beam" else 1.0,
+                         reason="forced")
+                     if method is not None else
+                     self.policy.route(mb.occupancy, mb.k, mb.recall_target,
+                                       sharded=self.sharded is not None,
+                                       segments=fanout,
+                                       stackable=stackable,
+                                       delta_frac=delta_frac,
+                                       tombstone_frac=tombstone_frac,
+                                       tile_density=density,
+                                       mesh_devices=mesh_devices))
         # warm start: valid caps only for exact routes (a cap bounds the
         # *exact* k-th distance; applying it to a budgeted beam could prune
         # candidates the direct beam would have returned)
@@ -305,58 +317,63 @@ class P2HEngine:
         caps = None
         if self.cache is not None and route.method != "beam" \
                 and not resilient:
-            if snap is not None:
-                # inserts may have grown max ||x||; the cap formula needs
-                # the current bound (monotone, so only ever grows)
-                self.cache.max_norm = max(self.cache.max_norm,
-                                          snap.max_norm)
-            # look up live slots only: pad rows replicate slot 0, and
-            # counting them would inflate hit/miss stats with dead work
-            c = np.full((len(mb.queries),), np.inf, np.float32)
-            c[:mb.occupancy] = self.cache.lookup(
-                mb.queries[:mb.occupancy], mb.k,
-                min_epoch=snap.last_delete_epoch if snap else 0)
-            if np.isfinite(c).any():
-                caps = c
-        t0 = time.perf_counter()
-        shard_kth = None
-        # the policy (not the library-level fan-out default) owns the
-        # stacked decision on the engine path: pass it down explicitly so
-        # snapshot/exchange auto-promotion never overrides a route the
-        # crossover knobs resolved to sequential, and route stats stay
-        # truthful about which schedule actually ran.  The policy's
-        # probe_tiles knob rides along for the two-pass program.
-        use_stacked = route.method == "stacked"
-        meta = None
-        degraded = False
-        if snap is not None and self._sharded_mutable:
-            # epoch-vector pin: the two-round exchange also reports each
-            # shard's local k-th bound for per-shard cache components
-            bd, bi, cnt, info = snap.query(
-                mb.queries, mb.k, method=route.method, frac=route.frac,
-                lambda_cap=caps, return_counters=True, return_info=True,
-                stacked=use_stacked, probe_tiles=route.probe_tiles,
-                probe_dtype=route.probe_dtype,
-                deadline=deadline if resilient else None,
-                resilience=self._supervisor if resilient else None)
-            shard_kth = info["shard_kth"]  # (S, B)
-            degraded = bool(info.get("degraded", False))
-            if resilient:
-                meta = {"complete": bool(info.get("complete", True)),
-                        "degraded": degraded, "shed": False,
-                        "missing_shards": tuple(
-                            info.get("missing_shards", ()))}
-        elif snap is not None:
-            bd, bi, cnt = snap.query(mb.queries, mb.k, method=route.method,
-                                     frac=route.frac, lambda_cap=caps,
-                                     return_counters=True,
-                                     stacked=use_stacked,
-                                     probe_tiles=route.probe_tiles,
-                                     probe_dtype=route.probe_dtype)
-        else:
-            bd, bi, cnt = self._run_backend(route, mb.queries, mb.k, caps)
-        bd, bi = np.asarray(bd), np.asarray(bi)
-        dt = time.perf_counter() - t0
+            with spans.span("p2h.cache.lookup"):
+                if snap is not None:
+                    # inserts may have grown max ||x||; the cap formula
+                    # needs the current bound (monotone, so only grows)
+                    self.cache.max_norm = max(self.cache.max_norm,
+                                              snap.max_norm)
+                # look up live slots only: pad rows replicate slot 0, and
+                # counting them would inflate hit/miss stats with dead
+                # work
+                c = np.full((len(mb.queries),), np.inf, np.float32)
+                c[:mb.occupancy] = self.cache.lookup(
+                    mb.queries[:mb.occupancy], mb.k,
+                    min_epoch=snap.last_delete_epoch if snap else 0)
+                if np.isfinite(c).any():
+                    caps = c
+        # the backend call up to its answers on the host
+        with spans.span("p2h.search") as search_span:
+            shard_kth = None
+            # the policy (not the library-level fan-out default) owns
+            # the stacked decision on the engine path: pass it down
+            # explicitly so snapshot/exchange auto-promotion never
+            # overrides a route the crossover knobs resolved to
+            # sequential, and route stats stay truthful about which
+            # schedule actually ran.  The policy's probe_tiles knob rides
+            # along for the two-pass program.
+            use_stacked = route.method == "stacked"
+            meta = None
+            degraded = False
+            if snap is not None and self._sharded_mutable:
+                # epoch-vector pin: the two-round exchange also reports
+                # each shard's local k-th bound for per-shard cache
+                # components
+                bd, bi, cnt, info = snap.query(
+                    mb.queries, mb.k, method=route.method, frac=route.frac,
+                    lambda_cap=caps, return_counters=True, return_info=True,
+                    stacked=use_stacked, probe_tiles=route.probe_tiles,
+                    probe_dtype=route.probe_dtype,
+                    deadline=deadline if resilient else None,
+                    resilience=self._supervisor if resilient else None)
+                shard_kth = info["shard_kth"]  # (S, B)
+                degraded = bool(info.get("degraded", False))
+                if resilient:
+                    meta = {"complete": bool(info.get("complete", True)),
+                            "degraded": degraded, "shed": False,
+                            "missing_shards": tuple(
+                                info.get("missing_shards", ()))}
+            elif snap is not None:
+                bd, bi, cnt = snap.query(
+                    mb.queries, mb.k, method=route.method, frac=route.frac,
+                    lambda_cap=caps, return_counters=True,
+                    stacked=use_stacked, probe_tiles=route.probe_tiles,
+                    probe_dtype=route.probe_dtype)
+            else:
+                bd, bi, cnt = self._run_backend(route, mb.queries, mb.k,
+                                                caps)
+            bd, bi = np.asarray(bd), np.asarray(bi)
+        self._search_s.append(search_span.duration_s)
 
         for slot, ticket in enumerate(mb.tickets):
             self._results[ticket] = (bd[slot], bi[slot])
@@ -366,24 +383,24 @@ class P2HEngine:
         # with +inf rows for the missing shards: skip the cache update
         # entirely rather than reason about partial validity
         if self.cache is not None and not degraded:
-            live = slice(0, mb.occupancy)
-            if shard_kth is not None:
-                self.cache.update_sharded(
-                    mb.queries[live], mb.k, shard_kth.T[live],
-                    epoch=snap.epoch,
-                    min_epoch=snap.last_delete_epoch)
-            else:
-                self.cache.update(
-                    mb.queries[live], mb.k, bd[live, mb.k - 1],
-                    epoch=snap.epoch if snap else 0,
-                    min_epoch=snap.last_delete_epoch if snap else 0)
+            with spans.span("p2h.cache.update"):
+                live = slice(0, mb.occupancy)
+                if shard_kth is not None:
+                    self.cache.update_sharded(
+                        mb.queries[live], mb.k, shard_kth.T[live],
+                        epoch=snap.epoch,
+                        min_epoch=snap.last_delete_epoch)
+                else:
+                    self.cache.update(
+                        mb.queries[live], mb.k, bd[live, mb.k - 1],
+                        epoch=snap.epoch if snap else 0,
+                        min_epoch=snap.last_delete_epoch if snap else 0)
         # stats
         self._route_counts[route.method] = (
             self._route_counts.get(route.method, 0) + 1)
         c8 = np.asarray(cnt)
         self._counters[route.method] = (
             self._counters.get(route.method, np.zeros(8, np.int64)) + c8)
-        self._latencies_s.append(dt)
         self._batches += 1
         self._queries_served += mb.occupancy
 
@@ -430,7 +447,13 @@ class P2HEngine:
         return out
 
     def stats(self) -> dict:
-        lat = sorted(self._latencies_s)
+        """This engine's counters since :meth:`reset_stats`;
+        ``latency_p50_ms`` / ``latency_p99_ms`` over its last
+        ``spans.RING`` batches' ``p2h.search`` spans.  ``spans`` and
+        ``span_counters`` are the process-wide recorder
+        (:mod:`repro.runtime.spans`), which every engine and index of
+        the process records into."""
+        lat = sorted(self._search_s)
 
         def pct(p):
             if not lat:
@@ -446,6 +469,8 @@ class P2HEngine:
             "counters": {m: search.SearchStats(c)
                          for m, c in self._counters.items()},
         }
+        rec = spans.snapshot()
+        out["spans"], out["span_counters"] = rec["spans"], rec["counters"]
         if self.cache is not None:
             out["lambda_cache"] = self.cache.stats()
         if self._router_version is not None:
@@ -481,9 +506,12 @@ class P2HEngine:
         return out
 
     def reset_stats(self):
+        """Zero this engine's counters, and the process-wide span
+        recorder with them (every engine's spans)."""
         self._route_counts.clear()
         self._counters.clear()
-        self._latencies_s.clear()
+        self._search_s.clear()
+        spans.reset()
         self._batches = 0
         self._queries_served = 0
         self._shed = {"queue_full": 0, "deadline": 0, "expired_batches": 0}

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bounds import EXACT
+from repro.runtime import spans
 
 __all__ = ["DeltaBuffer", "delta_topk"]
 
@@ -44,9 +45,15 @@ def _delta_topk(points, gids, queries, k: int):
 
 
 def delta_topk(points: np.ndarray, gids: np.ndarray, queries, k: int):
-    """Exact top-k over the delta rows; (dists (B,k), gids (B,k))."""
-    return _delta_topk(jnp.asarray(points), jnp.asarray(gids),
-                       jnp.asarray(queries), k)
+    """Exact top-k over the delta rows; (dists (B,k), gids (B,k)).
+    The host block is copied to the device whole, on every call: span
+    ``p2h.delta.upload`` bounds the host call that starts the copy (on a
+    TPU it returns before the transfer lands, and the device waits for
+    it later), counter ``delta_upload_bytes`` its bytes."""
+    with spans.span("p2h.delta.upload"):
+        points_dev, gids_dev = jnp.asarray(points), jnp.asarray(gids)
+    spans.count("delta_upload_bytes", points.nbytes + gids.nbytes)
+    return _delta_topk(points_dev, gids_dev, jnp.asarray(queries), k)
 
 
 class DeltaBuffer:

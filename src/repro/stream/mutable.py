@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core import search
 from repro.core.balltree import append_ones, normalize_query
+from repro.runtime import spans
 from repro.stream.compaction import CompactionPlan, CompactionPolicy
 from repro.stream.delta import DeltaBuffer
 from repro.stream.snapshot import DeltaView, Segment, Snapshot
@@ -192,12 +193,13 @@ class MutableP2HIndex:
         sharded front-end owns the id space); must be fresh."""
         x = np.asarray(point, np.float32).reshape(-1)
         assert x.shape == (self.dim,), (x.shape, self.dim)
-        with self._lock:
-            gid = self._insert_one_locked(x, gid=gid)
-            self._publish()
-            self._wal_log_insert(x, gid)
-            self._maybe_compact_locked()
-        self._wal_commit()
+        with spans.span("p2h.write.insert"):
+            with self._lock:
+                gid = self._insert_one_locked(x, gid=gid)
+                self._publish()
+                self._wal_log_insert(x, gid)
+                self._maybe_compact_locked()
+            self._wal_commit()
         return gid
 
     def insert_batch(self, points: np.ndarray,
@@ -211,14 +213,15 @@ class MutableP2HIndex:
         if gids is not None:
             assert len(gids) == len(pts), (len(gids), len(pts))
         out = np.empty((len(pts),), np.int32)
-        with self._lock:
-            for i, x in enumerate(pts):
-                out[i] = self._insert_one_locked(
-                    x, gid=None if gids is None else int(gids[i]))
-                self._wal_log_insert(pts[i], int(out[i]))
-            self._publish()
-            self._maybe_compact_locked()
-        self._wal_commit()
+        with spans.span("p2h.write.insert"):
+            with self._lock:
+                for i, x in enumerate(pts):
+                    out[i] = self._insert_one_locked(
+                        x, gid=None if gids is None else int(gids[i]))
+                    self._wal_log_insert(pts[i], int(out[i]))
+                self._publish()
+                self._maybe_compact_locked()
+            self._wal_commit()
         return out
 
     def _insert_one_locked(self, x: np.ndarray, *,
@@ -276,16 +279,17 @@ class MutableP2HIndex:
         fsync); the op is not acknowledged until that commit covers
         it."""
         gid = int(gid)
-        self._tl.in_delete = True
-        try:
-            with self._lock:
-                ok = self._delete_locked(gid)
-                if ok:
-                    self._wal_log(2, gid)  # OP_DELETE
-        finally:
-            self._tl.in_delete = False
-        if ok and commit:
-            self._wal_commit()
+        with spans.span("p2h.write.delete"):
+            self._tl.in_delete = True
+            try:
+                with self._lock:
+                    ok = self._delete_locked(gid)
+                    if ok:
+                        self._wal_log(2, gid)  # OP_DELETE
+            finally:
+                self._tl.in_delete = False
+            if ok and commit:
+                self._wal_commit()
         return ok
 
     def _delete_locked(self, gid: int) -> bool:
@@ -852,14 +856,16 @@ class MutableP2HIndex:
         the compactor's pre-built *and pre-warmed* stack (``prepub``):
         adopting it means the first query on the new epoch hits a
         program that was compiled off the query path."""
-        self._epoch += 1
-        prev = self._snapshot
-        snap = self._make_snapshot()
-        snap.adopt_stacked_from(prev)
-        if prepub is not None and prepub.get("stacked") is not None:
-            snap.adopt_prebuilt_stacked(prepub["stacked"],
-                                        prepub["sources"])
-        self._snapshot = snap
+        with spans.span("p2h.publish"):
+            self._epoch += 1
+            prev = self._snapshot
+            snap = self._make_snapshot()
+            snap.adopt_stacked_from(prev)
+            if prepub is not None and prepub.get("stacked") is not None:
+                snap.adopt_prebuilt_stacked(prepub["stacked"],
+                                            prepub["sources"])
+            self._snapshot = snap
+        spans.count("publishes")
 
     # ------------------------------------------------------------------
     # persistence (through repro.checkpoint)

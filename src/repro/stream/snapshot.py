@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.core import search
 from repro.core.balltree import FlatTree
+from repro.runtime import spans
 from repro.stream.delta import delta_topk
 
 __all__ = ["Segment", "Snapshot", "DeltaView", "ShardedSnapshot"]
@@ -188,8 +189,11 @@ class Snapshot:
         if stk is None and self.segments:
             base = self.__dict__.get("_stacked_base")
             if base is not None:
-                stk = base.with_updated_ids(
-                    self.__dict__.get("_stacked_pending") or {})
+                # the deletes' cost paid on the query path
+                with spans.span("p2h.stacked.ids_rewrite"):
+                    stk = base.with_updated_ids(
+                        self.__dict__.get("_stacked_pending") or {})
+                spans.count("ids_rewrites")
             else:
                 from repro.kernels.stacked_sweep import StackedLeaves
 
@@ -368,17 +372,18 @@ class Snapshot:
         One definition shared by :meth:`query`, the benches' skip
         profiles and the live-skip regression fence, so every consumer
         measures the same entry state."""
-        q = jnp.asarray(q, jnp.float32)
-        B = q.shape[0]
-        bd = jnp.full((B, k), jnp.inf, jnp.float32)
-        bi = jnp.full((B, k), -1, jnp.int32)
-        verified = 0
-        for view in self.deltas:
-            dd, di = delta_topk(view.points, view.gids, q, k)
-            bd, bi = search.merge_topk(jnp.concatenate([bd, dd], axis=1),
-                                       jnp.concatenate([bi, di], axis=1),
-                                       k)
-            verified += view.live * B
+        with spans.span("p2h.delta.scan"):
+            q = jnp.asarray(q, jnp.float32)
+            B = q.shape[0]
+            bd = jnp.full((B, k), jnp.inf, jnp.float32)
+            bi = jnp.full((B, k), -1, jnp.int32)
+            verified = 0
+            for view in self.deltas:
+                dd, di = delta_topk(view.points, view.gids, q, k)
+                bd, bi = search.merge_topk(
+                    jnp.concatenate([bd, dd], axis=1),
+                    jnp.concatenate([bi, di], axis=1), k)
+                verified += view.live * B
         return bd, bi, verified
 
     def _use_stacked(self, method: str, stacked: bool | None) -> bool:
