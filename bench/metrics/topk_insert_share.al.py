@@ -1,0 +1,7 @@
+"""Share of a fixed k-step top-k insertion loop that the stacked sweep
+kernel runs in ``sun397.al`` (stacked sweep kernel layer)."""
+from kernel_steps import topk_insert_share
+
+
+def read(ctx):
+    return topk_insert_share(ctx, "sun397.al")

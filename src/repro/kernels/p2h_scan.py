@@ -14,8 +14,9 @@ center-preference order:
     bound** (Theorem 2) of every query in the block is >= lambda;
   * inside a live tile, points are pruned with the **point-level ball
     bound** (Corollary 1) and **point-level cone bound** (Theorem 3) before
-    the |<x,q>| verification matmul, then ``k`` vectorized insert passes
-    update the running top-k.
+    the |<x,q>| verification matmul, then at most ``k`` vectorized insert
+    passes update the running top-k: only as many as the most candidates
+    of one query below its running k-th.
 
 A frozen tree is a one-segment stack, so the sweep runs the stacked
 kernel (:func:`repro.kernels.stacked_sweep.stacked_sweep`) with a
@@ -58,7 +59,7 @@ def p2h_sweep(
     the block -- the ``pl.when`` elision in the kernel).
     """
     tiles = (pts_tiles, ids_tiles, rx_tiles, xc_tiles, xs_tiles, leaf_cnorm)
-    d, i, s = stacked_sweep(
+    d, i, s, _ = stacked_sweep(
         *(a[None] for a in tiles), queries, qnorm, cap, leaf_ip[None],
         leaf_lb[None], visit[None], k=k, bq=bq, use_ball=use_ball,
         use_cone=use_cone, interpret=interpret)

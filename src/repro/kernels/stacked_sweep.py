@@ -646,6 +646,17 @@ def _first_index(mask, iota, fill):
     return jnp.min(jnp.where(mask, iota, fill), axis=1, keepdims=True)
 
 
+def _insert_trips(cand, kth, k):
+    """Steps of a tile's top-k insertion loop that can change the running
+    top-k: ``min(k, most candidates of one row strictly below its running
+    k-th)``.  Each insertion takes a candidate below the row's k-th as it
+    stood before the loop (the k-th only falls), and a row's first step
+    that inserts nothing leaves it unchanged for every later step, so the
+    steps past this count are no-ops in every row."""
+    below = jnp.sum(jnp.where(cand < kth, 1, 0), axis=1, keepdims=True)
+    return jnp.minimum(jnp.max(below), k)
+
+
 def stacked_sweep_kernel(
     # scalar prefetch
     visit_ref,  # SMEM (N * nqb * n_visit,) i32 -- flattened visit order
@@ -670,13 +681,14 @@ def stacked_sweep_kernel(
     # outputs
     out_d_ref,  # (bq, k)  f32 -- this segment's top-k (unsorted)
     out_i_ref,  # (bq, k)  i32
-    out_s_ref,  # (1, 128) i32 -- skipped-tile count, lane-broadcast
+    out_s_ref,  # (1, 128) i32 -- lane 0 skipped tiles, lane 1 scanned
+    #              tiles, lanes 2+ insertion steps run
     # scratch
     topd,       # VMEM (bq, k) f32 -- running per-segment top-k
     topi,       # VMEM (bq, k) i32
     glob,       # VMEM (nqb, bq, k) f32 -- per-block *global* top-k
     #             values, threaded across the (sequential) segment axis
-    nskip,      # SMEM (1,) i32
+    counts,     # SMEM (3,) i32 -- the three counts of out_s_ref
     *,
     k: int,
     bq: int,
@@ -721,7 +733,9 @@ def stacked_sweep_kernel(
     def _init():  # fresh segment (or query block): resume from the seed
         topd[...] = sd_ref[...]
         topi[...] = si_ref[...]
-        nskip[0] = 0
+        counts[0] = 0
+        counts[1] = 0
+        counts[2] = 0
 
     blk = step_ref[...]
     lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
@@ -731,15 +745,15 @@ def stacked_sweep_kernel(
     lb = col[bq:2 * bq]
     cn, ts, sa, sb = (col[2 * bq + r:2 * bq + r + 1] for r in range(4))
 
+    kth = jnp.max(topd[...], axis=1, keepdims=True)  # (bq, 1)
     gmax = jnp.max(glob[pl.ds(i, 1)][0], axis=1, keepdims=True)  # (bq, 1)
-    lam = jnp.minimum(jnp.minimum(jnp.max(topd[...], axis=1, keepdims=True),
-                                  gmax), cap_ref[...])  # (bq, 1)
+    lam = jnp.minimum(jnp.minimum(kth, gmax), cap_ref[...])  # (bq, 1)
     active = lb < lam  # Theorem 2 prune (pad tiles: lb=+inf)
     any_active = jnp.max(jnp.where(active, 1.0, 0.0)) > 0.0
 
     @pl.when(jnp.logical_not(any_active))
     def _count_skip():
-        nskip[0] = nskip[0] + 1
+        counts[0] = counts[0] + 1
 
     @pl.when(any_active)
     def _scan_tile():
@@ -781,34 +795,47 @@ def stacked_sweep_kernel(
             err = qn * sa + sq_ref[...] * sb  # (bq, 1)
             cand = jnp.where(keep, jnp.abs(raw) + err, _NEG_FILL)
 
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
-        iota_n = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
+        # the insertion loop runs only the steps that can change a row's
+        # top-k (_insert_trips): after pass A's seed most tiles hold no
+        # candidate below the running k-th, and the loop is skipped
+        n_iter = _insert_trips(cand, kth, k)
+        counts[1] = counts[1] + 1
+        counts[2] = counts[2] + n_iter
 
-        def insert(_, carry):
-            td, ti, cd = carry
-            m = jnp.min(cd, axis=1, keepdims=True)
-            am = _first_index(cd == m, iota_n, cd.shape[1])
-            wv = jnp.max(td, axis=1, keepdims=True)
-            wa = _first_index(td == wv, iota_k, k)
-            better = m < wv
-            oh_w = (iota_k == wa) & better
-            oh_c = iota_n == am
-            win_id = jnp.max(jnp.where(oh_c, ids, -1), axis=1, keepdims=True)
-            td = jnp.where(oh_w, m, td)
-            ti = jnp.where(oh_w, win_id, ti)
-            cd = jnp.where(oh_c & better, _NEG_FILL, cd)
-            return td, ti, cd
+        @pl.when(n_iter > 0)
+        def _insert():
+            iota_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+            iota_n = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
 
-        td, ti, _ = jax.lax.fori_loop(
-            0, k, insert, (topd[...], topi[...], cand))
-        topd[...] = td
-        topi[...] = ti
+            def insert(_, carry):
+                td, ti, cd = carry
+                m = jnp.min(cd, axis=1, keepdims=True)
+                am = _first_index(cd == m, iota_n, cd.shape[1])
+                wv = jnp.max(td, axis=1, keepdims=True)
+                wa = _first_index(td == wv, iota_k, k)
+                better = m < wv
+                oh_w = (iota_k == wa) & better
+                oh_c = iota_n == am
+                win_id = jnp.max(jnp.where(oh_c, ids, -1), axis=1,
+                                 keepdims=True)
+                td = jnp.where(oh_w, m, td)
+                ti = jnp.where(oh_w, win_id, ti)
+                cd = jnp.where(oh_c & better, _NEG_FILL, cd)
+                return td, ti, cd
+
+            td, ti, _ = jax.lax.fori_loop(
+                0, n_iter, insert, (topd[...], topi[...], cand))
+            topd[...] = td
+            topi[...] = ti
 
     @pl.when(j == n_tiles - 1)
     def _write_out():
         out_d_ref[...] = topd[...]
         out_i_ref[...] = topi[...]
-        out_s_ref[...] = jnp.full(out_s_ref.shape, nskip[0], jnp.int32)
+        lane_s = jax.lax.broadcasted_iota(jnp.int32, out_s_ref.shape, 1)
+        out_s_ref[...] = jnp.where(
+            lane_s == 0, counts[0],
+            jnp.where(lane_s == 1, counts[1], counts[2]))
         # fold this segment's top-k values into the per-block global
         # running state (k-smallest of the 2k values; same insertion
         # pattern as the tile scan, values only -- ids stay per-segment)
@@ -925,9 +952,12 @@ def stacked_sweep(
     """pallas_call wrapper: grid ``(N segments, query blocks, tiles)``.
 
     Returns unsorted ``(dists (N, B, k), ids (N, B, k),
-    skips (N, B//bq, 1))``; ``skips`` counts block-granular tile skips
-    per segment, **including** the force-skipped pad tiles of ragged /
-    empty / all-tombstone segments (they are part of the launch).
+    skips (N, B//bq, 1), steps (N, B//bq, 2))``; ``skips`` counts
+    block-granular tile skips per segment, **including** the
+    force-skipped pad tiles of ragged / empty / all-tombstone segments
+    (they are part of the launch); ``steps`` counts the steps that
+    scanned a tile and the top-k insertion steps those scans ran (at
+    most ``k`` each).
     ``seed_d``/``seed_i`` seed each segment's running top-k (the probe
     handoff of the two-pass sweep); ``global_seed`` seeds the in-launch
     global top-k values every segment's threshold folds in (pass B gets
@@ -1017,7 +1047,7 @@ def stacked_sweep(
                     pltpu.VMEM((bq, k), jnp.float32),
                     pltpu.VMEM((bq, k), jnp.int32),
                     pltpu.VMEM((g, bq, k), jnp.float32),  # global top-k
-                    pltpu.SMEM((1,), jnp.int32),
+                    pltpu.SMEM((3,), jnp.int32),
                 ],
             ),
             out_shape=[
@@ -1029,7 +1059,7 @@ def stacked_sweep(
         )(visit[:, c0:c0 + g].reshape(-1), queries[b0:b1], qnorm[b0:b1],
           sq[b0:b1], cap[b0:b1], global_seed[b0:b1], seed_d[:, b0:b1],
           seed_i[:, b0:b1], step[:, c0:c0 + g], pts_tiles, rows)
-        return out_d, out_i, out_s[:, :, :, 0]
+        return out_d, out_i, out_s[:, :, :, 0], out_s[:, :, 0, 1:3]
 
     g = _launch_blocks(nqb, N * nv)
     outs = [launch(c0, min(g, nqb - c0)) for c0 in range(0, nqb, g)]
@@ -1062,6 +1092,22 @@ def _quant_probe_operands(probe_dtype, ops, qpts, qscale, radii, cnorm,
     qops = dict(ops, pts_tiles=qpts, queries=qq)
     return qops, dict(probe_dtype=probe_dtype, sq=sqv, tile_scale=ts,
                       slack_a=sa, slack_b=sb)
+
+
+def _sweep_runner(use_kernel, interpret, **kw):
+    """The per-pass sweep both programs run: ``run(**operands)`` returns
+    :func:`stacked_sweep`'s ``(dists, ids, skips, steps)``; the jnp twin
+    counts no steps (zeros)."""
+    from repro.kernels import ref
+
+    if use_kernel:
+        return functools.partial(stacked_sweep, interpret=interpret, **kw)
+
+    def run(**ops):
+        d, i, skips = ref.stacked_sweep_ref(**ops, **kw)
+        return d, i, skips, jnp.zeros(skips.shape[:2] + (2,), jnp.int32)
+
+    return run
 
 
 def _widened_probe_cap(cap, pd, k):
@@ -1120,7 +1166,6 @@ def _run_stacked(arrays, queries, lambda_cap, extra_d, extra_i, seg_shard,
     compaction changes values, not the trace.
     """
     from repro.core import search
-    from repro.kernels import ref
 
     arrays = dict(arrays)
     qpts = arrays.pop("qpts", None)
@@ -1129,10 +1174,8 @@ def _run_stacked(arrays, queries, lambda_cap, extra_d, extra_i, seg_shard,
     ops, B0 = prepare_stacked_operands(
         stk, queries, frac=frac, bq=bq, lambda_cap=lambda_cap,
         lane_pad=use_kernel)
-    fn = (functools.partial(stacked_sweep, interpret=interpret)
-          if use_kernel else ref.stacked_sweep_ref)
-    run = functools.partial(fn, k=k, bq=bq, use_ball=use_ball,
-                            use_cone=use_cone)
+    run = _sweep_runner(use_kernel, interpret, k=k, bq=bq,
+                        use_ball=use_ball, use_cone=use_cone)
     visit = ops["visit"]
     N, nqb, n_visit = visit.shape
     true_row = jnp.arange(N) < n_true  # bucket-pad rows: swept (force-
@@ -1169,17 +1212,20 @@ def _run_stacked(arrays, queries, lambda_cap, extra_d, extra_i, seg_shard,
         qops, quant_kw = _quant_probe_operands(
             probe_dtype, ops, qpts, qscale, arrays["leaf_radii"],
             arrays["leaf_cnorm"], d)
-        da, ia, skips_a = run(**dict(qops, visit=visit[:, :, :p]),
-                              global_seed=gseed, **quant_kw)
+        da, ia, skips_a, steps_a = run(
+            **dict(qops, visit=visit[:, :, :p]), global_seed=gseed,
+            **quant_kw)
         pd, _ = search.merge_topk_planes(da, ia, k)
         cap_b = _widened_probe_cap(ops["cap"], pd, k)
-        bd, bi, skips = run(**dict(ops, cap=cap_b), global_seed=gseed)
+        bd, bi, skips, steps = run(**dict(ops, cap=cap_b),
+                                   global_seed=gseed)
+        steps = steps + steps_a
         probe_skips = jnp.sum(
             jnp.where(true_row[:, None, None], skips_a, 0))
     elif 0 < p < n_visit:
         # pass A: probe the top-p preference tiles of every segment
-        da, ia, skips_a = run(**dict(ops, visit=visit[:, :, :p]),
-                              global_seed=gseed)
+        da, ia, skips_a, steps_a = run(**dict(ops, visit=visit[:, :, :p]),
+                                       global_seed=gseed)
         pd, _ = search.merge_topk_planes(da, ia, k)
         cap_b = jnp.minimum(ops["cap"], pd[:, k - 1:k])  # lambda_probe
         # pass B: remaining tiles under lambda_probe, per-segment
@@ -1191,24 +1237,27 @@ def _run_stacked(arrays, queries, lambda_cap, extra_d, extra_i, seg_shard,
         # validity) -- lambda_probe carries the cross-segment probe
         # bound instead, and the global state tightens past it as
         # completed segments fold in.
-        bd, bi, skips_b = run(**dict(ops, visit=visit[:, :, p:],
-                                     cap=cap_b),
-                              seed_d=da, seed_i=ia, global_seed=gseed)
+        bd, bi, skips_b, steps_b = run(**dict(ops, visit=visit[:, :, p:],
+                                              cap=cap_b),
+                                       seed_d=da, seed_i=ia,
+                                       global_seed=gseed)
         skips = skips_a + skips_b
+        steps = steps_a + steps_b
         probe_skips = jnp.sum(
             jnp.where(true_row[:, None, None], skips_a, 0))
     else:  # p == 0 (single pass) or p == n_visit (probe IS the sweep)
-        bd, bi, skips = run(**ops, global_seed=gseed)
+        bd, bi, skips, steps = run(**ops, global_seed=gseed)
         probe_skips = (jnp.sum(jnp.where(true_row[:, None, None],
                                          skips, 0))
                        if p else jnp.int32(0))
-    return _finish_stacked(bd, bi, skips, probe_skips, extra_d, extra_i,
-                           seg_shard, n_true, stk.n_leaves, k=k, B0=B0,
-                           num_shards=num_shards, sort_planes=sort_planes,
-                           nqb=nqb, n_visit=n_visit)
+    return _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d,
+                           extra_i, seg_shard, n_true, stk.n_leaves, k=k,
+                           B0=B0, num_shards=num_shards,
+                           sort_planes=sort_planes, nqb=nqb,
+                           n_visit=n_visit)
 
 
-def _finish_stacked(bd, bi, skips, probe_skips, extra_d, extra_i,
+def _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d, extra_i,
                     seg_shard, n_true, n_leaves, *, k, B0, num_shards,
                     sort_planes, nqb, n_visit):
     """Cross-source finish shared by the single-launch
@@ -1216,7 +1265,10 @@ def _finish_stacked(bd, bi, skips, probe_skips, extra_d, extra_i,
     programs, on full bucket-padded planes: the in-launch global merge
     of the per-segment planes (+ the caller's extra candidates, e.g. the
     delta scan) into one (B, k) answer, the per-shard k-th reductions,
-    the optional plane sort, and the counter conventions."""
+    the optional plane sort, and the counter conventions.  The launch's
+    last output is ``(3,)`` i32: the probe pass's skipped tiles, then
+    both passes' scanned steps and top-k insertion steps over the true
+    segments (:func:`stacked_sweep`'s ``steps``)."""
     from repro.core import search
 
     true_row = jnp.arange(bd.shape[0]) < n_true
@@ -1255,7 +1307,11 @@ def _finish_stacked(bd, bi, skips, probe_skips, extra_d, extra_i,
                 .at[2].set(n_true.astype(jnp.int32)
                            * jnp.int32(nqb * n_visit) - total_skip)
                 .at[7].set(total_skip))
-    return bd, bi, fd, fi, counters, seg_skips, shard_kth, probe_skips
+    steps = jnp.sum(jnp.where(true_row[:, None, None], steps, 0),
+                    axis=(0, 1)).astype(jnp.int32)
+    launch_counts = jnp.concatenate(
+        [jnp.reshape(probe_skips, (1,)).astype(jnp.int32), steps])
+    return bd, bi, fd, fi, counters, seg_skips, shard_kth, launch_counts
 
 
 @functools.partial(
@@ -1296,7 +1352,6 @@ def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
     collective entirely: one gather at the end is the whole exchange.
     """
     from repro.core import search
-    from repro.kernels import ref
 
     B0 = queries.shape[0]
     Bp = _ceil_to(B0, bq)
@@ -1326,10 +1381,8 @@ def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
         ops, _ = prepare_stacked_operands(
             stk_l, q, frac=frac, bq=bq, lambda_cap=cap,
             lane_pad=use_kernel)
-        fn = (functools.partial(stacked_sweep, interpret=interpret)
-              if use_kernel else ref.stacked_sweep_ref)
-        run = functools.partial(fn, k=k, bq=bq, use_ball=use_ball,
-                                use_cone=use_cone)
+        run = _sweep_runner(use_kernel, interpret, k=k, bq=bq,
+                            use_ball=use_ball, use_cone=use_cone)
         visit = ops["visit"]
         gather = functools.partial(jax.lax.all_gather,
                                    axis_name=mesh_axis, axis=0,
@@ -1343,46 +1396,49 @@ def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
             qops, quant_kw = _quant_probe_operands(
                 probe_dtype, ops, qpts_l, qscale_l, arrs["leaf_radii"],
                 arrs["leaf_cnorm"], d)
-            da, ia, sk_a = run(**dict(qops, visit=visit[:, :, :p]),
-                               global_seed=gs, **quant_kw)
+            da, ia, sk_a, st_a = run(**dict(qops, visit=visit[:, :, :p]),
+                                     global_seed=gs, **quant_kw)
             pd, _ = search.merge_topk_planes(gather(da), gather(ia), k)
             cap_b = _widened_probe_cap(ops["cap"], pd, k)
-            bd_l, bi_l, sk_l = run(**dict(ops, cap=cap_b),
-                                   global_seed=gs)
+            bd_l, bi_l, sk_l, st_l = run(**dict(ops, cap=cap_b),
+                                         global_seed=gs)
+            st_l = st_l + st_a
             psk_l = sk_a
         elif 0 < p < n_visit:
-            da, ia, sk_a = run(**dict(ops, visit=visit[:, :, :p]),
-                               global_seed=gs)
+            da, ia, sk_a, st_a = run(**dict(ops, visit=visit[:, :, :p]),
+                                     global_seed=gs)
             # the lambda exchange as a collective: every device's probe
             # planes meet here; the merged k-th is the same valid bound
             # the single launch threads sequentially
             pd, _ = search.merge_topk_planes(gather(da), gather(ia), k)
             cap_b = jnp.minimum(ops["cap"], pd[:, k - 1:k])
-            bd_l, bi_l, sk_b = run(**dict(ops, visit=visit[:, :, p:],
-                                          cap=cap_b),
-                                   seed_d=da, seed_i=ia, global_seed=gs)
+            bd_l, bi_l, sk_b, st_b = run(
+                **dict(ops, visit=visit[:, :, p:], cap=cap_b),
+                seed_d=da, seed_i=ia, global_seed=gs)
             sk_l = sk_a + sk_b
+            st_l = st_a + st_b
             psk_l = sk_a
         else:  # p == 0 (single pass) or p == n_visit (probe IS the sweep)
-            bd_l, bi_l, sk_l = run(**ops, global_seed=gs)
+            bd_l, bi_l, sk_l, st_l = run(**ops, global_seed=gs)
             psk_l = sk_l if p else jnp.zeros_like(sk_l)
-        return gather(bd_l), gather(bi_l), gather(sk_l), gather(psk_l)
+        return (gather(bd_l), gather(bi_l), gather(sk_l), gather(st_l),
+                gather(psk_l))
 
     in_spec = jax.tree.map(lambda _: _P(mesh_axis), arrays)
     # replication checking off: the gathered outputs are replicated by
     # construction, which the static checker cannot prove
-    bd, bi, skips, probe_sk = jax.shard_map(
+    bd, bi, skips, steps, probe_sk = jax.shard_map(
         local, mesh=mesh,
         in_specs=(in_spec, _P(), _P(), _P()),
-        out_specs=(_P(), _P(), _P(), _P()), check_vma=False,
+        out_specs=(_P(), _P(), _P(), _P(), _P()), check_vma=False,
     )(arrays, queries, cap0, gseed)
     true_row = jnp.arange(bd.shape[0]) < n_true
     probe_skips = (jnp.sum(jnp.where(true_row[:, None, None],
                                      probe_sk, 0))
                    if p else jnp.int32(0))
-    return _finish_stacked(bd, bi, skips, probe_skips, extra_d, extra_i,
-                           seg_shard, n_true, arrays["n_leaves"], k=k,
-                           B0=B0, num_shards=num_shards,
+    return _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d,
+                           extra_i, seg_shard, n_true, arrays["n_leaves"],
+                           k=k, B0=B0, num_shards=num_shards,
                            sort_planes=sort_planes, nqb=nqb,
                            n_visit=n_visit)
 
@@ -1714,9 +1770,9 @@ def _call_run_stacked(stk: StackedLeaves, queries, k, *, frac, bq,
                  probe_dtype=pdt, num_shards=num_shards,
                  has_extra=has_extra, sort_planes=sort_planes)
     if Np != N:  # per-segment outputs slice back to the true rows
-        bd, bi, fd, fi, counters, seg_skips, shard_kth, probe_skips = out
+        bd, bi, fd, fi, counters, seg_skips, shard_kth, launch_counts = out
         out = (bd[:N], bi[:N], fd, fi, counters, seg_skips[:N],
-               shard_kth, probe_skips)
+               shard_kth, launch_counts)
     return out, p, pdt
 
 
@@ -1840,11 +1896,16 @@ def stacked_sweep_query(stk: StackedLeaves, queries, k: int = 1, *,
             shard_bounds=shard_bounds, use_kernel=use_kernel,
             interpret=interpret, sort_planes=False, mesh=mesh,
             mesh_axis=mesh_axis)
-    _, _, fd, fi, counters, seg_skips, shard_kth, probe_skips = out
+    _, _, fd, fi, counters, seg_skips, shard_kth, launch_counts = out
     # the device time as the host sees it: the launch's own outputs, so
-    # the first blocking read below (probe_skips) waits for nothing more
+    # the first blocking read below (launch_counts) waits for nothing more
     with spans.span("p2h.device_wait"):
-        jax.block_until_ready((fd, fi, counters, probe_skips))
+        jax.block_until_ready((fd, fi, counters, launch_counts))
+    probe_skips, scan_steps, insert_steps = (
+        int(c) for c in np.asarray(launch_counts))
+    if scan_steps:  # the jnp twin counts no steps
+        spans.count("stacked_scan_steps", scan_steps)
+        spans.count("stacked_insert_steps", insert_steps)
     B = int(np.atleast_2d(np.asarray(queries)).shape[0])
     nqb = -(-B // bq)
     n_visit = _n_visit(stk, frac)
@@ -1853,13 +1914,13 @@ def stacked_sweep_query(stk: StackedLeaves, queries, k: int = 1, *,
         live = np.asarray(stk.valid).sum(axis=1).astype(np.int64)
         stk._derived["live_tiles"] = live
     forced = nqb * np.maximum(0, n_visit - live)  # invalid tiles visited
-    probe_scanned = int(stk.num_segments * nqb * p) - int(probe_skips)
+    probe_scanned = int(stk.num_segments * nqb * p) - probe_skips
     info = {
         "seg_skips": seg_skips,
         "forced_skips": forced,
         "shard_kth": shard_kth,
         "probe": {"tiles": p, "scanned": probe_scanned,
-                  "skipped": int(probe_skips), "dtype": pdt},
+                  "skipped": probe_skips, "dtype": pdt},
         "mesh_devices": max(1, _mesh_axis_size(mesh, mesh_axis)),
     }
     return fd, fi, counters, info

@@ -104,8 +104,8 @@ def test_stacked_kernel_matches_ref_with_padding_edges(use_ball, use_cone):
     q = normalize_query(_mkdata(9, seed=4, dim=DIM + 1))  # 9: pad path
     ops, B0 = prepare_stacked_operands(stk, jnp.asarray(q), bq=8,
                                        lane_pad=True)  # the TPU shape
-    kd, ki, ks = stacked_sweep(**ops, k=5, use_ball=use_ball,
-                               use_cone=use_cone, interpret=True)
+    kd, ki, ks, _ = stacked_sweep(**ops, k=5, use_ball=use_ball,
+                                  use_cone=use_cone, interpret=True)
     rd, ri, rs = stacked_sweep_ref(**ops, k=5, use_ball=use_ball,
                                    use_cone=use_cone)
     np.testing.assert_allclose(np.sort(np.asarray(kd), axis=2),
@@ -146,6 +146,114 @@ def test_stacked_search_exact_vs_bruteforce_and_entry_cap():
         assert np.array_equal(np.asarray(fci), eg)
         assert (np.asarray(ccnt)[C_TILE_SKIP]
                 >= np.asarray(cnt)[C_TILE_SKIP])
+
+
+# ------------------------------------- the insertion loop's trip count
+def _insert_case_segments(case, seed):
+    """A stack for one edge case of the kernel's top-k insertion loop."""
+    if case == "dead_tiles":  # ragged pad tiles, an all-tombstone segment
+        return _ragged_segments(seed=seed)
+    rng = np.random.default_rng(seed)
+    if case == "ties":  # every point four times: ties at every rank
+        raws = [np.repeat(rng.normal(size=(n, DIM)), 4, axis=0)
+                for n in (40, 25)]
+    else:
+        raws = [rng.normal(size=(n, DIM)) for n in (150, 110, 90)]
+    if case == "short_tiles":  # tiles with fewer live points than k
+        raws.append(rng.normal(size=(1, DIM)))
+    segs, gid = [], 0
+    for u, raw in enumerate(raws):
+        n = len(raw)
+        segs.append(_Seg(u, raw.astype(np.float32), np.arange(gid, gid + n)))
+        gid += n
+    if case == "short_tiles":  # all but every 7th point of one segment
+        import dataclasses
+
+        t = segs[1].tree
+        pid = np.asarray(t.point_ids)
+        segs[1].tree = dataclasses.replace(
+            t, point_ids=np.where(pid % 7 == 0, pid, -1))
+    return segs
+
+
+@pytest.mark.parametrize("case", ["cold", "short_tiles", "dead_tiles",
+                                  "ties", "refed"])
+@pytest.mark.parametrize("k", [1, 10, 50])
+def test_insert_loop_runs_only_the_steps_that_can_change_the_topk(
+        monkeypatch, k, case):
+    """The kernel's insertion loop runs ``_insert_trips`` steps instead of
+    k.  Per-segment planes and skip counts are bit-identical to the
+    fixed k-step loop's, the merged answers equal brute force, and the
+    loop runs at most k steps per scanned tile -- none when the seed
+    already holds each segment's top-k and its points are gone from the
+    tiles (pass B after a probe that found every winner)."""
+    from repro.kernels import stacked_sweep as sweep_mod
+
+    segs = _insert_case_segments(case, seed=11)
+    stk = StackedLeaves.from_segments(segs)
+    q = normalize_query(_mkdata(9, seed=12, dim=DIM + 1))
+    ops, B0 = prepare_stacked_operands(stk, jnp.asarray(q), bq=8,
+                                       lane_pad=True)
+    seed = {}
+    if case == "refed":
+        d0, i0, _, _ = stacked_sweep(**ops, k=k, interpret=True)
+        held = np.isin(np.asarray(ops["ids_tiles"]), np.asarray(i0))
+        ops = dict(ops, ids_tiles=jnp.where(held, -1, ops["ids_tiles"]))
+        seed = dict(seed_d=d0, seed_i=i0)
+
+    def sweep():
+        return [np.asarray(a) for a in stacked_sweep(
+            **ops, k=k, interpret=True, **seed)]
+
+    d, i, skips, steps = sweep()
+    with monkeypatch.context() as mp:  # the fixed k-step loop
+        mp.setattr(sweep_mod, "_insert_trips", lambda cand, kth, k: k)
+        fd, fi, fskips, fsteps = sweep()
+    np.testing.assert_array_equal(d, fd)
+    np.testing.assert_array_equal(i, fi)
+    np.testing.assert_array_equal(skips, fskips)
+    scan, ins = steps.sum(axis=(0, 1))
+    fscan, fins = fsteps.sum(axis=(0, 1))
+    assert scan == fscan > 0
+    assert fins == k * fscan  # the fixed loop did run k steps a tile
+    assert 0 <= ins <= k * scan
+    if case == "refed":
+        assert ins == 0
+        np.testing.assert_array_equal(d, np.asarray(d0))
+        np.testing.assert_array_equal(i, np.asarray(i0))
+    X, G = _live_union(segs)
+    ed, _ = exact_search(jnp.asarray(X), jnp.asarray(q), k=k)
+    md, mi = (np.asarray(a) for a in _merged(d[:, :B0], i[:, :B0], k))
+    np.testing.assert_allclose(md, np.asarray(ed), rtol=1e-4, atol=1e-5)
+    # returned ids are live, distinct, and carry their own distances
+    row_of = {int(g): r for r, g in enumerate(G)}
+    for r in range(B0):
+        assert len(set(mi[r].tolist())) == k
+        true = np.abs(X[[row_of[int(g)] for g in mi[r]]] @ q[r])
+        np.testing.assert_allclose(md[r], true, rtol=1e-4, atol=1e-5)
+
+
+def test_stacked_query_records_scan_and_insert_steps():
+    """The launch's scan and insertion counts reach the span counters on
+    the kernel path, through the read the launch already makes; the jnp
+    twin counts nothing."""
+    from repro.kernels.stacked_sweep import stacked_sweep_query
+    from repro.runtime import spans
+
+    segs = _insert_case_segments("cold", seed=13)
+    stk = StackedLeaves.from_segments(segs)
+    q = jnp.asarray(normalize_query(_mkdata(9, seed=14, dim=DIM + 1)))
+    k = 10
+    spans.reset()
+    try:
+        stacked_sweep_query(stk, q, k, use_kernel=False)
+        assert "stacked_scan_steps" not in spans.snapshot()["counters"]
+        stacked_sweep_query(stk, q, k, use_kernel=True, interpret=True)
+        c = spans.snapshot()["counters"]
+    finally:
+        spans.reset()
+    assert c["stacked_scan_steps"] > 0
+    assert 0 < c["stacked_insert_steps"] <= k * c["stacked_scan_steps"]
 
 
 def test_stacked_concat_repads_mixed_tile_grids():

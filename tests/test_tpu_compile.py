@@ -5,7 +5,8 @@ described rather than attached, so Mosaic's refusals (block shapes off
 the (8, 128) tiling, scalar-memory overflow, unsupported ops) surface
 here, at no chip time, in a process held to the CPU.  Shapes are the
 Music-100 deployment's: d + 1 = 101 columns (lane-padded to 128),
-n0 = 128, query blocks of 8, k = 10.
+n0 = 128, query blocks of 8, k = 10; the stacked passes also compile at
+SUN397's widths: d + 1 = 513 columns (lane-padded to 640), k = 50.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers import every
@@ -23,6 +24,10 @@ from repro.kernels.stacked_sweep import stacked_sweep
 
 DP, N0, BQ, K = 128, 128, 8, 10
 B = 32  # query rows: 4 blocks of 8
+
+#: (lane-padded width, k) of each deployment the stacked passes serve
+WIDTHS = pytest.mark.parametrize("dp,k", [(DP, K), (640, 50)],
+                                 ids=["music100", "sun397"])
 
 
 @pytest.fixture(scope="module")
@@ -63,30 +68,32 @@ def test_p2h_sweep_f32_compiles(one_chip):
                                        interpret=False), args)
 
 
-def _stacked_args(S, N, L, n_visit, dtype):
-    return (S((N, L, N0, DP), dtype), S((N, L, N0), jnp.int32),
+def _stacked_args(S, N, L, n_visit, dtype, dp=DP):
+    return (S((N, L, N0, dp), dtype), S((N, L, N0), jnp.int32),
             S((N, L, N0)), S((N, L, N0)), S((N, L, N0)), S((N, L, 1)),
-            S((B, DP), dtype), S((B, 1)), S((B, 1)), S((N, B, L)),
+            S((B, dp), dtype), S((B, 1)), S((B, 1)), S((N, B, L)),
             S((N, B, L)), S((N, B // BQ, n_visit), jnp.int32))
 
 
-def test_stacked_sweep_f32_main_pass_compiles(one_chip):
+@WIDTHS
+def test_stacked_sweep_f32_main_pass_compiles(one_chip, dp, k):
     """Pass B: the f32 rescan, seeded with pass A's per-segment state and
-    the in-launch global top-k."""
+    the in-launch global top-k, with the scan and insertion counts."""
     S = functools.partial(_spec, one_chip)
     N, L = 4, 512
 
     def main_pass(*a):
         *ops, sd, si, gs = a
-        return stacked_sweep(*ops, k=K, bq=BQ, interpret=False, seed_d=sd,
+        return stacked_sweep(*ops, k=k, bq=BQ, interpret=False, seed_d=sd,
                              seed_i=si, global_seed=gs)
 
-    args = _stacked_args(S, N, L, L, jnp.float32) + (
-        S((N, B, K)), S((N, B, K), jnp.int32), S((B, K)))
+    args = _stacked_args(S, N, L, L, jnp.float32, dp) + (
+        S((N, B, k)), S((N, B, k), jnp.int32), S((B, k)))
     _compiled_kernel(main_pass, args)
 
 
-def test_stacked_sweep_bf16_probe_pass_compiles(one_chip):
+@WIDTHS
+def test_stacked_sweep_bf16_probe_pass_compiles(one_chip, dp, k):
     """Pass A: the bf16 probe over the first preferred tiles, scores
     widened by the per-tile quantization slack."""
     S = functools.partial(_spec, one_chip)
@@ -94,10 +101,10 @@ def test_stacked_sweep_bf16_probe_pass_compiles(one_chip):
 
     def probe_pass(*a):
         *ops, sq, sa, sb = a
-        return stacked_sweep(*ops, k=K, bq=BQ, interpret=False,
+        return stacked_sweep(*ops, k=k, bq=BQ, interpret=False,
                              probe_dtype="bf16", sq=sq, slack_a=sa,
                              slack_b=sb)
 
-    args = _stacked_args(S, N, L, probe, jnp.bfloat16) + (
+    args = _stacked_args(S, N, L, probe, jnp.bfloat16, dp) + (
         S((B, 1)), S((N, L, 1)), S((N, L, 1)))
     _compiled_kernel(probe_pass, args)
