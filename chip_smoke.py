@@ -15,9 +15,11 @@ hyperplane queries served at k = 10 through ``P2HEngine``:
   the points deleted: the engine routes to the ``stacked`` two-pass
   sweep (Mosaic kernel, bf16 probe);
 * ``--chips 4`` runs only a sharded form of phase B: a
-  ``ShardedMutableP2HIndex`` with ``set_mesh(make_serving_mesh(4))``,
-  checked bit for bit against the same snapshot's single-device launch
-  and checked to hold its planes on all four devices.
+  ``ShardedMutableP2HIndex`` of 4 shards, shard ``s`` placed on chip
+  ``s`` (``devices=``), served over the 4-chip mesh, checked bit for bit
+  against the same snapshot's single-device launch, checked to keep each
+  shard's planes and trees on its own chip, and checked that chip 0's
+  peak memory is at most 1.5x the smallest chip's.
 
 Every phase is checked against ``exact_search`` on the live set: the
 distances must agree within the forward error bound of two f32 dot
@@ -167,22 +169,24 @@ def phase_a(data, queries, seed):
     return st
 
 
-def build_churned(data, seed, sharded):
+def build_churned(data, seed, devices=None):
     """The phase-B index: 4 sealed segments + a live delta per shard, 1%
-    of the points deleted.  Returns ``(index, live gids)``."""
+    of the points deleted; with ``devices``, one shard placed on each.
+    Returns ``(index, live gids)``."""
     import numpy as np
 
     from repro.stream import CompactionPolicy, MutableP2HIndex
     from repro.stream.sharded import ShardedMutableP2HIndex
 
-    shards = 2 if sharded else 1
+    sharded = devices is not None
+    shards = len(devices) if sharded else 1
     policy = CompactionPolicy(delta_capacity=SEG_ROWS // shards,
                               tombstone_frac=0.5, max_segments=8)
     t0 = time.perf_counter()
     if sharded:
         idx = ShardedMutableP2HIndex.from_data(
             data[:SEG_ROWS], num_shards=shards, n0=N0, policy=policy,
-            seed=seed)
+            seed=seed, devices=devices)
     else:
         idx = MutableP2HIndex.from_data(data[:SEG_ROWS], n0=N0,
                                         policy=policy, seed=seed)
@@ -211,7 +215,7 @@ def phase_b(data, queries, seed):
                                              warm_stacked)
     from repro.serve import P2HEngine
 
-    idx, live = build_churned(data, seed, sharded=False)
+    idx, live = build_churned(data, seed)
     engine = P2HEngine(idx)
     sys_d, sys_i = engine.query(queries, k=K)
     st = engine.stats()
@@ -242,12 +246,9 @@ def phase_mesh(data, queries, seed, devices):
     import numpy as np
 
     from repro.core.balltree import normalize_query
-    from repro.kernels.stacked_sweep import concat_cached
-    from repro.launch.mesh import make_serving_mesh
     from repro.serve import P2HEngine
 
-    idx, live = build_churned(data, seed, sharded=True)
-    idx.set_mesh(make_serving_mesh(len(devices)))
+    idx, live = build_churned(data, seed, devices=devices)
     engine = P2HEngine(idx)
     sys_d, sys_i = engine.query(queries, k=K)
     st = engine.stats()
@@ -258,8 +259,19 @@ def phase_mesh(data, queries, seed, devices):
             "mesh: expected the stacked route across every device")
     require(st["warm_failures"] == 0, "mesh: warm-up failures")
     check_answers("mesh", sys_d, sys_i, data, live, queries)
-    # the same snapshot, single-device launch vs the mesh launch
+    # each shard's planes and round-1 trees on its own chip
     snap = idx.snapshot()
+    for s, (shard, dev) in enumerate(zip(snap.shards, devices)):
+        stk = shard.stacked_leaves()
+        trees = [seg.resident_tree(dev)[0] for seg in shard.segments]
+        on = ({dev} == stk.pts.devices() == stk.ids.devices()
+              and all(t.points.devices() == {dev} for t in trees))
+        require(on, f"mesh: shard {s} has state off chip {s}")
+    peaks = peak_bytes(devices)
+    say("mesh peak_bytes_in_use per chip:", peaks)
+    require(peaks[0] <= 1.5 * min(peaks),
+            "mesh: chip 0 peaks above 1.5x the smallest chip")
+    # the same snapshot, single-device launch vs the mesh launch
     qn = normalize_query(queries)
     md, mi = snap.query(qn, K, method="stacked", stacked=True)
     sd, si = dataclasses.replace(snap, mesh=None).query(
@@ -268,23 +280,6 @@ def phase_mesh(data, queries, seed, devices):
             "mesh launch differs from the single-device launch")
     say("mesh vs single-device launch on one snapshot: bit-exact")
     check_answers("mesh (direct)", md, mi, data, live, queries)
-    # the round-2 stack the exchange concatenated: its placed planes
-    stk = concat_cached([s.stacked_leaves() for s in snap.shards
-                         if s.segments])
-    placed = {key: v for key, v in stk._derived.items()
-              if key.startswith("geom:mesh:")}
-    require(placed, "mesh: no placed planes on the round-2 stack")
-    for key, planes in placed.items():
-        for name, a in planes.items():
-            devs = {sh.device for sh in a.addressable_shards}
-            require(len(devs) == len(devices)
-                    and a.shape[0] % len(devices) == 0
-                    and all(sh.data.shape[0] == a.shape[0] // len(devices)
-                            for sh in a.addressable_shards),
-                    f"mesh: plane {name} is not split over "
-                    f"{len(devices)} devices")
-    say(f"mesh placed planes: {sorted(next(iter(placed.values())))} each "
-        f"split over {len(devices)} devices by segment")
     return st
 
 

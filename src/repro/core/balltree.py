@@ -103,14 +103,23 @@ class FlatTree:
 # ----------------------------------------------------------------------
 
 
-def _split(points: np.ndarray, idx: np.ndarray, rng: np.random.Generator):
-    """Paper Algorithm 2 (seed-grow rule) with a degenerate-split guard."""
-    sub = points[idx]
+def _sqdist(sub: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """``((sub - v) ** 2).sum(axis=1)`` with one temporary, squared in
+    place (the same float64 values)."""
+    t = sub - v
+    np.multiply(t, t, out=t)
+    return t.sum(axis=1)
+
+
+def _split(sub: np.ndarray, idx: np.ndarray, rng: np.random.Generator):
+    """Paper Algorithm 2 (seed-grow rule) with a degenerate-split guard,
+    over the node's rows ``sub`` (already gathered: ``data[idx]``)."""
     v = sub[rng.integers(len(idx))]
-    xl = sub[np.argmax(((sub - v) ** 2).sum(axis=1))]
-    xr = sub[np.argmax(((sub - xl) ** 2).sum(axis=1))]
-    dl = ((sub - xl) ** 2).sum(axis=1)
-    dr = ((sub - xr) ** 2).sum(axis=1)
+    dl = _sqdist(sub, v)
+    xl = sub[np.argmax(dl)]
+    dl = _sqdist(sub, xl)  # distances to xl: the seed pick and the split
+    xr = sub[np.argmax(dl)]
+    dr = _sqdist(sub, xr)
     left_mask = dl <= dr
     if left_mask.all() or (~left_mask).all():
         # all points coincide (duplicates) -- split in half arbitrarily
@@ -141,7 +150,9 @@ def build_tree(
     rng = np.random.default_rng(seed)
 
     nodes = []  # (center, radius, count, left, right, leaf_slot, depth)
-    leaf_point_idx: list[np.ndarray] = []
+    #: per leaf slot: point ids, their rows and squared distances to the
+    #: leaf center (rows gathered once, reused for the cone tables)
+    leaf_point_idx: list[tuple] = []
 
     sys.setrecursionlimit(max(10000, sys.getrecursionlimit()))
     max_depth = [0]
@@ -153,19 +164,20 @@ def build_tree(
         sub = data[idx]
         if len(idx) <= n0:  # leaf
             center = sub.mean(axis=0)
-            radius = float(np.sqrt(((sub - center) ** 2).sum(axis=1).max()))
+            d2 = _sqdist(sub, center)
+            radius = float(np.sqrt(d2.max()))
             slot = len(leaf_point_idx)
-            leaf_point_idx.append(idx)
+            leaf_point_idx.append((idx, sub, d2))
             nodes[node_id] = (center, radius, len(idx), -1, -1, slot, depth)
         else:
-            li, ri = _split(data, idx, rng)
+            li, ri = _split(sub, idx, rng)
             lid = rec(li, depth + 1)
             rid = rec(ri, depth + 1)
             # Lemma 1: centroid linearity (BC-Tree Alg. 4 line 16)
             cl, _, nl = nodes[lid][0], nodes[lid][1], nodes[lid][2]
             cr, nr = nodes[rid][0], nodes[rid][2]
             center = (cl * nl + cr * nr) / (nl + nr)
-            radius = float(np.sqrt(((sub - center) ** 2).sum(axis=1).max()))
+            radius = float(np.sqrt(_sqdist(sub, center).max()))
             nodes[node_id] = (center, radius, len(idx), lid, rid, -1, depth)
         return node_id
 
@@ -198,10 +210,9 @@ def build_tree(
     leaf_node_ids = np.where(node_leaf >= 0)[0]
     for node_id in leaf_node_ids:
         slot = int(node_leaf[node_id])
-        idx = leaf_point_idx[slot]
+        idx, sub, d2 = leaf_point_idx[slot]
         c = np.asarray(nodes[node_id][0])
-        sub = data[idx]
-        r_x = np.sqrt(((sub - c) ** 2).sum(axis=1))
+        r_x = np.sqrt(d2)
         order = np.argsort(-r_x, kind="stable")  # descending rx (Alg. 4 l.9)
         idx, sub, r_x = idx[order], sub[order], r_x[order]
         xn = np.sqrt((sub**2).sum(axis=1))

@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import search
 from repro.core.balltree import FlatTree, build_tree
 from repro.parallel.sharding import mesh_signature
+from repro.runtime import spans
 
 __all__ = ["ShardedP2HIndex", "two_round_exchange", "warm_round1"]
 
@@ -287,34 +288,45 @@ def two_round_exchange(shards, queries, k: int = 1, *, frac1: float = 0.25,
             probe_tiles=probe_tiles, probe_dtype=probe_dtype,
             mesh=mesh, mesh_axis=mesh_axis, deadline=deadline,
             sup=resilience)
-    q = jnp.asarray(np.atleast_2d(np.asarray(queries)), jnp.float32)
+    q_host = np.asarray(np.atleast_2d(np.asarray(queries)), np.float32)
+    q = jnp.asarray(q_host)
     B = q.shape[0]
     counters = np.zeros((8,), np.int64)
     ext = (None if lambda_cap is None
-           else jnp.asarray(lambda_cap, jnp.float32).reshape(-1))
+           else np.asarray(lambda_cap, np.float32).reshape(-1))
     lam0 = None
     round1_kth = []
     parts_d, parts_i = [], []
     if method != "beam":
         _record_round1(B, k, frac1)  # template for pre-publish warmup
-        lam = jnp.full((B,), jnp.inf, jnp.float32) if ext is None else ext
-        for s in shards:
-            bd1, bi1, c1 = s.query(q, k, method="beam", frac=frac1,
-                                   return_counters=True)
-            counters += np.asarray(c1, np.int64)
-            kth1 = jnp.asarray(bd1)[:, k - 1]
-            round1_kth.append(np.asarray(kth1))
-            lam = jnp.minimum(lam, kth1)
-            # round-1 candidates (incl. the exact delta scan) feed the
-            # final merge, so round 2 need not rescan the deltas
-            parts_d.append(jnp.asarray(bd1))
-            parts_i.append(jnp.asarray(bi1))
-        lam0 = lam
+        with spans.span("p2h.exchange.round1"):
+            # every shard's call is started before any answer is read:
+            # placed shards run on their own devices at the same time
+            pending = []
+            for si, s in enumerate(shards):
+                with spans.span("p2h.exchange.shard", shard=si):
+                    pending.append(_round1_start(s, q, q_host, k, frac1))
+            lam = (np.full((B,), np.inf, np.float32) if ext is None
+                   else ext)
+            for res in pending:
+                bd1, bi1, c1 = res() if callable(res) else res
+                counters += np.asarray(c1, np.int64)
+                bd1, bi1 = np.asarray(bd1), np.asarray(bi1)
+                round1_kth.append(bd1[:, k - 1])
+                lam = np.minimum(lam, bd1[:, k - 1])
+                # round-1 candidates (incl. the exact delta scan) feed
+                # the final merge, so round 2 need not rescan the deltas
+                parts_d.append(bd1)
+                parts_i.append(bi1)
+        lam0 = jnp.asarray(lam)
     base = "sweep" if method == "stacked" else method
-    stk_merged, stk_kth, cnt_stk = _stacked_round2(
-        shards, q, k, method=method, stacked=stacked, lam0=lam0,
-        probe_tiles=probe_tiles, probe_dtype=probe_dtype,
-        mesh=mesh, mesh_axis=mesh_axis)
+    with spans.span("p2h.exchange.round2"):
+        stk_merged, stk_kth, cnt_stk = _stacked_round2(
+            shards, q, k, method=method, stacked=stacked, lam0=lam0,
+            probe_tiles=probe_tiles, probe_dtype=probe_dtype,
+            mesh=mesh, mesh_axis=mesh_axis)
+        if stk_merged is not None:
+            stk_merged = tuple(np.asarray(a) for a in stk_merged)
     if cnt_stk is not None:
         counters += cnt_stk
     if stk_merged is not None:
@@ -322,8 +334,8 @@ def two_round_exchange(shards, queries, k: int = 1, *, frac1: float = 0.25,
         # stackable shard's segments and reduced the per-shard k-ths --
         # it contributes a single already-merged candidate list, never a
         # host-side per-segment merge loop
-        parts_d.append(jnp.asarray(stk_merged[0]))
-        parts_i.append(jnp.asarray(stk_merged[1]))
+        parts_d.append(stk_merged[0])
+        parts_i.append(stk_merged[1])
     round2_kth = []
     for si, s in enumerate(shards):
         if si in stk_kth:
@@ -335,16 +347,19 @@ def two_round_exchange(shards, queries, k: int = 1, *, frac1: float = 0.25,
                               lambda_cap=lam0, return_counters=True,
                               include_deltas=method == "beam", **kw)
         counters += np.asarray(cnt, np.int64)
-        round2_kth.append(np.asarray(jnp.asarray(bd)[:, k - 1]))
-        parts_d.append(jnp.asarray(bd))
-        parts_i.append(jnp.asarray(bi))
-    if parts_d:
-        bd, bi = search.merge_topk(jnp.concatenate(parts_d, axis=1),
-                                   jnp.concatenate(parts_i, axis=1), k)
         bd, bi = np.asarray(bd), np.asarray(bi)
-    else:
-        bd = np.full((B, k), np.inf, np.float32)
-        bi = np.full((B, k), -1, np.int32)
+        round2_kth.append(bd[:, k - 1])
+        parts_d.append(bd)
+        parts_i.append(bi)
+    with spans.span("p2h.exchange.merge"):
+        if parts_d:
+            bd, bi = search.merge_topk(
+                jnp.asarray(np.concatenate(parts_d, axis=1)),
+                jnp.asarray(np.concatenate(parts_i, axis=1)), k)
+            bd, bi = np.asarray(bd), np.asarray(bi)
+        else:
+            bd = np.full((B, k), np.inf, np.float32)
+            bi = np.full((B, k), -1, np.int32)
     if return_info:
         r2 = (np.stack(round2_kth) if round2_kth
               else np.zeros((0, B), np.float32))
@@ -362,6 +377,33 @@ def two_round_exchange(shards, queries, k: int = 1, *, frac1: float = 0.25,
         }
         return bd, bi, counters, info
     return bd, bi, counters
+
+
+def _round1_start(s, q, q_host, k: int, frac1: float):
+    """Start shard ``s``'s round-1 beam.  A snapshot pin runs on its own
+    device and returns a callable that reads the answer later; any other
+    backend answers now.  A placed shard gets the queries on its device
+    (counted in ``exchange_upload_bytes``)."""
+    dispatch = getattr(s, "dispatch", None)
+    if dispatch is None:
+        return s.query(q, k, method="beam", frac=frac1,
+                       return_counters=True)
+    dev = getattr(s, "device", None)
+    if dev is not None:
+        q = jax.device_put(q_host, dev)
+        spans.count("exchange_upload_bytes", q_host.nbytes)
+    return dispatch(q, k, method="beam", frac=frac1).result
+
+
+def _placed(shards, mesh, mesh_axis) -> bool:
+    """Whether shard ``s`` of ``shards`` lives on device ``s`` of the 1-D
+    ``mesh`` for every ``s`` (a placed sharded index)."""
+    if mesh is None or tuple(mesh.axis_names) != (mesh_axis,):
+        return False
+    devices = list(np.asarray(mesh.devices).flat)
+    return len(devices) == len(shards) and all(
+        getattr(s, "device", None) == dev
+        for s, dev in zip(shards, devices))
 
 
 def _stacked_round2(shards, q, k, *, method, stacked, lam0, probe_tiles,
@@ -396,11 +438,30 @@ def _stacked_round2(shards, q, k, *, method, stacked, lam0, probe_tiles,
         if (fanout < STACKED_FANOUT_DEFAULT
                 or tile_density(all_segs) < STACKED_DENSITY_DEFAULT):
             return None, {}, None
-    from repro.kernels.stacked_sweep import concat_cached, stacked_sweep_query
+    from repro.kernels.stacked_sweep import (PlacedStacks, concat_cached,
+                                             stacked_sweep_query)
 
+    is_bc = getattr(stackable[0][1], "variant", "bc") == "bc"
+    if _placed(shards, mesh, mesh_axis):
+        # each shard's stack is built on its own device, all to one tile
+        # count, and the launch takes them as they lie
+        tiles = _common_tiles(s for _, s in stackable)
+        by_shard = dict(stackable)
+        combined = PlacedStacks(
+            tuple(by_shard[si].stacked_leaves(min_tiles=tiles)
+                  if si in by_shard else None
+                  for si in range(len(shards))),
+            mesh, mesh_axis)
+        fd, fi, cnt, info = stacked_sweep_query(
+            combined, q, k, lambda_cap=lam0, probe_tiles=probe_tiles,
+            probe_dtype=probe_dtype, probe_route="round2",
+            use_ball=is_bc, use_cone=is_bc,
+            use_kernel=True if method == "pallas" else None)
+        shard_kth = np.asarray(info["shard_kth"])  # (S, B)
+        kths = {si: shard_kth[si] for si, _ in stackable}
+        return (fd, fi), kths, np.asarray(cnt, np.int64)
     stks = [s.stacked_leaves() for _, s in stackable]
     combined = concat_cached(stks)
-    is_bc = getattr(stackable[0][1], "variant", "bc") == "bc"
     # probe_route="round2": the sweep enters with lambda0, the exchanged
     # round-1 k-th -- the same tightening the probe pass would recreate
     # -- so the route's default is single-pass (measured: the probe
@@ -415,6 +476,15 @@ def _stacked_round2(shards, q, k, *, method, stacked, lam0, probe_tiles,
     shard_kth = np.asarray(info["shard_kth"])  # (S_stackable, B)
     kths = {si: shard_kth[row] for row, (si, _) in enumerate(stackable)}
     return (fd, fi), kths, np.asarray(cnt, np.int64)
+
+
+def _common_tiles(snaps) -> int:
+    """The tile count every placed shard's stack is built to: the
+    stacked grid's quantized count over all shards' segments."""
+    from repro.kernels.stacked_sweep import _ceil_to, _tile_quantum
+
+    most = max(seg.tree.num_leaves for s in snaps for seg in s.segments)
+    return _ceil_to(most, _tile_quantum(most))
 
 
 def _resilient_exchange(shards, queries, k, *, frac1, method, frac,

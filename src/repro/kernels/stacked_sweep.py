@@ -65,7 +65,8 @@ from repro.runtime import spans
 
 __all__ = ["StackedLeaves", "stacked_sweep", "stacked_sweep_search",
            "stacked_sweep_query", "prepare_stacked_operands",
-           "concat_cached", "tile_density", "resolve_probe_tiles",
+           "concat_cached", "PlacedStacks", "tile_density",
+           "resolve_probe_tiles",
            "resolve_probe_dtype", "resolve_stacked_backend",
            "quantization_slack", "probe_bytes_per_tile",
            "warm_stacked", "stacked_compile_stats",
@@ -227,6 +228,20 @@ def _bucket_segments(n: int) -> int:
     return _ceil_to(n, 16)
 
 
+#: the array fields of :class:`StackedLeaves`
+_PLANES = ("pts", "ids", "rx", "xc", "xs", "leaf_centers", "leaf_radii",
+           "leaf_cnorm", "valid", "n_leaves")
+
+
+def _device_of(a):
+    """The one device a committed array lives on; None for host arrays
+    and arrays JAX may still move (uncommitted)."""
+    if not isinstance(a, jax.Array) or not a.committed:
+        return None
+    devs = a.devices()
+    return next(iter(devs)) if len(devs) == 1 else None
+
+
 #: ``StackedLeaves._derived`` keys that depend only on tile *geometry*
 #: (safe to share through ids-plane-only rewrites); everything else is
 #: dropped by :meth:`StackedLeaves.with_updated_ids`.
@@ -335,17 +350,26 @@ class StackedLeaves:
         return hit
 
     # ------------------------------------------------------------------
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the stack's own planes (derived copies left out)."""
+        return sum(int(getattr(self, f).nbytes) for f in _PLANES)
+
     @classmethod
-    def from_segments(cls, segments) -> "StackedLeaves":
+    def from_segments(cls, segments, *, device=None,
+                      tiles: int = 0) -> "StackedLeaves":
         """Stack ``segments`` (objects with ``.uid``, ``.tree`` --
         a :class:`repro.core.balltree.FlatTree` -- and ``.gids``, the
-        local-id -> global-id table) into one padded tile grid."""
+        local-id -> global-id table) into one padded tile grid, placed
+        on ``device`` (None: JAX's default placement).  ``tiles`` pads
+        the tile axis up to at least that count (stacks that must share
+        one grid, as the per-device blocks of a placed sharded index)."""
         segments = tuple(segments)
         assert segments, "cannot stack zero segments"
         t0 = segments[0].tree
         n0, d = t0.n0, t0.d
         max_leaves = max(t.tree.num_leaves for t in segments)
-        L = _ceil_to(max_leaves, _tile_quantum(max_leaves))
+        L = max(_ceil_to(max_leaves, _tile_quantum(max_leaves)), int(tiles))
         N = len(segments)
         pts = np.zeros((N, L, n0, d), np.float32)
         ids = np.full((N, L, n0), -1, np.int32)
@@ -370,13 +394,13 @@ class StackedLeaves:
             cnorm[s, :Ls, 0] = np.asarray(t.leaf_cnorm)
             n_leaves[s] = Ls
         valid = (ids >= 0).any(axis=2)
-        return cls(pts=jnp.asarray(pts), ids=jnp.asarray(ids),
-                   rx=jnp.asarray(rx), xc=jnp.asarray(xc),
-                   xs=jnp.asarray(xs), leaf_centers=jnp.asarray(centers),
-                   leaf_radii=jnp.asarray(radii),
-                   leaf_cnorm=jnp.asarray(cnorm),
-                   valid=jnp.asarray(valid), n_leaves=jnp.asarray(n_leaves),
-                   uids=tuple(seg.uid for seg in segments), n0=n0, d=d)
+        planes = dict(pts=pts, ids=ids, rx=rx, xc=xc, xs=xs,
+                      leaf_centers=centers, leaf_radii=radii,
+                      leaf_cnorm=cnorm, valid=valid, n_leaves=n_leaves)
+        planes = (jax.tree.map(jnp.asarray, planes) if device is None
+                  else jax.device_put(planes, device))
+        return cls(**planes, uids=tuple(seg.uid for seg in segments),
+                   n0=n0, d=d)
 
     def with_updated_ids(self, changed: dict) -> "StackedLeaves":
         """New stack with the ids/valid planes of ``changed`` segments
@@ -396,8 +420,11 @@ class StackedLeaves:
             uids[s] = seg.uid
         keep = {key: v for key, v in self._derived.items()
                 if key in _GEOMETRY_DERIVED or key.startswith("geom:")}
-        return dataclasses.replace(self, ids=jnp.asarray(ids),
-                                   valid=jnp.asarray((ids >= 0).any(axis=2)),
+        live = (ids, (ids >= 0).any(axis=2))
+        dev = _device_of(self.ids)
+        ids_dev, valid_dev = (jax.tree.map(jnp.asarray, live) if dev is None
+                              else jax.device_put(live, dev))
+        return dataclasses.replace(self, ids=ids_dev, valid=valid_dev,
                                    uids=tuple(uids), _derived=keep)
 
     @staticmethod
@@ -413,8 +440,12 @@ class StackedLeaves:
         assert all(s.n0 == n0 and s.d == d for s in stacks), \
             "stacks disagree on tiling"
         L = max(s.num_tiles for s in stacks)
+        # stacks placed on different devices meet on the first one's
+        dev = _device_of(stacks[0].ids)
 
         def padL(a, fill):
+            if dev is not None and _device_of(a) != dev:
+                a = jax.device_put(a, dev)
             pad = L - a.shape[1]
             if pad == 0:
                 return a
@@ -434,7 +465,9 @@ class StackedLeaves:
             leaf_cnorm=jnp.concatenate(
                 [padL(s.leaf_cnorm, 0.0) for s in stacks]),
             valid=jnp.concatenate([padL(s.valid, False) for s in stacks]),
-            n_leaves=jnp.concatenate([s.n_leaves for s in stacks]),
+            n_leaves=jnp.concatenate(
+                [s.n_leaves if dev is None else jax.device_put(s.n_leaves, dev)
+                 for s in stacks]),
             uids=tuple(u for s in stacks for u in s.uids),
             n0=n0, d=d)
 
@@ -1251,14 +1284,14 @@ def _run_stacked(arrays, queries, lambda_cap, extra_d, extra_i, seg_shard,
                                          skips, 0))
                        if p else jnp.int32(0))
     return _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d,
-                           extra_i, seg_shard, n_true, stk.n_leaves, k=k,
+                           extra_i, seg_shard, true_row, stk.n_leaves, k=k,
                            B0=B0, num_shards=num_shards,
                            sort_planes=sort_planes, nqb=nqb,
                            n_visit=n_visit)
 
 
 def _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d, extra_i,
-                    seg_shard, n_true, n_leaves, *, k, B0, num_shards,
+                    seg_shard, true_row, n_leaves, *, k, B0, num_shards,
                     sort_planes, nqb, n_visit):
     """Cross-source finish shared by the single-launch
     (:func:`_run_stacked`) and mesh (:func:`_run_stacked_mesh`)
@@ -1268,10 +1301,11 @@ def _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d, extra_i,
     the optional plane sort, and the counter conventions.  The launch's
     last output is ``(3,)`` i32: the probe pass's skipped tiles, then
     both passes' scanned steps and top-k insertion steps over the true
-    segments (:func:`stacked_sweep`'s ``steps``)."""
+    segments (:func:`stacked_sweep`'s ``steps``).  ``true_row`` (Np,)
+    marks the rows that are real segments (the rest are bucket pad)."""
     from repro.core import search
 
-    true_row = jnp.arange(bd.shape[0]) < n_true
+    n_true = jnp.sum(true_row.astype(jnp.int32))
     fd, fi = search.merge_topk_planes(bd, bi, k, extra_d=extra_d,
                                       extra_i=extra_i)
     fd, fi = fd[:B0], fi[:B0]
@@ -1322,7 +1356,7 @@ def _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d, extra_i,
                      "has_extra", "sort_planes"),
 )
 def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
-                      seg_shard, n_true, *, mesh, mesh_axis, n0, d, k,
+                      seg_shard, true_row, *, mesh, mesh_axis, n0, d, k,
                       frac, bq, use_ball, use_cone, use_kernel, interpret,
                       probe_tiles, probe_dtype, num_shards, has_extra,
                       sort_planes):
@@ -1350,6 +1384,8 @@ def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
     from the single-device launch.  Single-pass dispatches (``p == 0``,
     e.g. the exchange's round 2 under ``lambda0``) skip the probe
     collective entirely: one gather at the end is the whole exchange.
+    ``true_row`` (Np,) marks the real segments: a placed index's
+    per-device blocks each end in their own pad rows.
     """
     from repro.core import search
 
@@ -1432,12 +1468,11 @@ def _run_stacked_mesh(arrays, queries, lambda_cap, extra_d, extra_i,
         in_specs=(in_spec, _P(), _P(), _P()),
         out_specs=(_P(), _P(), _P(), _P(), _P()), check_vma=False,
     )(arrays, queries, cap0, gseed)
-    true_row = jnp.arange(bd.shape[0]) < n_true
     probe_skips = (jnp.sum(jnp.where(true_row[:, None, None],
                                      probe_sk, 0))
                    if p else jnp.int32(0))
     return _finish_stacked(bd, bi, skips, steps, probe_skips, extra_d,
-                           extra_i, seg_shard, n_true, arrays["n_leaves"],
+                           extra_i, seg_shard, true_row, arrays["n_leaves"],
                            k=k, B0=B0, num_shards=num_shards,
                            sort_planes=sort_planes, nqb=nqb,
                            n_visit=n_visit)
@@ -1483,77 +1518,184 @@ def resolve_probe_dtype(probe_dtype, probe_tiles_resolved: int) -> str:
     return "f32" if probe_tiles_resolved == 0 else probe_dtype
 
 
-def _pad_rows(a, pad: int, fill):
-    """Append ``pad`` constant-filled rows along the leading axis."""
-    if pad == 0:
+#: the fill of each launch plane's pad rows and pad tiles: dead tiles
+#: (ids -1, ``valid`` False, rx -1) the sweep force-skips; int8 scales
+#: pad with 1.0, the zero-guard of :meth:`StackedLeaves.quantized_pts`
+_FILL = dict(pts=0.0, ids=-1, rx=-1.0, xc=0.0, xs=0.0, leaf_centers=0.0,
+             leaf_radii=0.0, leaf_cnorm=0.0, valid=False, n_leaves=0,
+             qpts=0, qscale=1.0)
+
+
+def _pad_plane(name: str, a, rows: int, tiles: int):
+    """Plane ``name`` padded to ``rows`` segments and, where it has a
+    tile axis (every plane but ``n_leaves``), to ``tiles`` tiles."""
+    w = [(0, rows - a.shape[0])] + [(0, 0)] * (a.ndim - 1)
+    if a.ndim > 1:
+        w[1] = (0, tiles - a.shape[1])
+    if all(p == (0, 0) for p in w):
         return a
-    w = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
-    return jnp.pad(a, w, constant_values=fill)
+    return jnp.pad(a, w, constant_values=_FILL[name])
 
 
 def _bucketed_arrays(stk: StackedLeaves, *, use_kernel: bool,
-                     multiple: int = 1, probe_dtype: str = "f32"):
+                     multiple: int = 1, probe_dtype: str = "f32",
+                     rows: int | None = None, tiles: int | None = None):
     """The launch's arrays dict with the segment axis padded to the
-    :func:`_bucket_segments` bucket.  Pad rows are dead (``valid=False``,
-    ``n_leaves=0``, ids -1) so the sweep force-skips them; the padded
-    geometry planes are memoized in ``_derived`` under ``geom:``-prefixed
-    keys (shared through tombstone republishes -- geometry never moves),
-    the ids-derived pads under plain keys (rebuilt when the planes do
-    move).  ``multiple`` further rounds the bucket up (the mesh path
-    needs the segment axis divisible by the device count; pad rows are
-    free dead weight, and the memo keys already carry ``Np`` so bucket
-    variants coexist).  ``probe_dtype`` != "f32" adds the quantized
-    probe plane (``qpts``, zero-padded: exact zeros quantize exactly)
-    and the int8 per-tile scales (``qscale``, pad 1.0 -- the zero-guard
-    convention of :meth:`StackedLeaves.quantized_pts`).  Returns
-    ``(arrays, padded segment count)``."""
+    :func:`_bucket_segments` bucket (or to ``rows``) and the tile axis
+    to ``tiles`` (default: the stack's own).  Pad rows and tiles are
+    dead (``valid=False``, ``n_leaves=0``, ids -1) so the sweep
+    force-skips them; the padded geometry planes are memoized in
+    ``_derived`` under ``geom:``-prefixed keys (shared through tombstone
+    republishes -- geometry never moves), the ids-derived pads under
+    plain keys (rebuilt when the planes do move).  Pads are made where
+    the stack lives.  ``multiple`` further rounds the bucket up (the
+    mesh path needs the segment axis divisible by the device count; pad
+    rows are free dead weight, and the memo keys carry the padded shape
+    so variants coexist).  ``probe_dtype`` != "f32" adds the quantized
+    probe plane (``qpts``) and the int8 per-tile scales (``qscale``).
+    Returns ``(arrays, padded segment count)``."""
     N = stk.num_segments
-    Np = _bucket_segments(N)
+    Np = _bucket_segments(N) if rows is None else int(rows)
     if multiple > 1:
         Np = _ceil_to(Np, multiple)
-    pad = Np - N
+    L = stk.num_tiles if tiles is None else int(tiles)
+    tag = "lane" if use_kernel else "raw"
     pts = stk.padded_pts() if use_kernel else stk.pts
     quant = {}
     if probe_dtype != "f32":
-        qpts, qscale = stk.quantized_pts(probe_dtype,
-                                         lane_pad=use_kernel)
-        if pad == 0:
-            quant = dict(qpts=qpts)
-            if qscale is not None:
-                quant["qscale"] = qscale
-        else:
-            qkey = (f"geom:quant:bucket:{Np}:{probe_dtype}:"
-                    f"{'lane' if use_kernel else 'raw'}")
-            quant = stk._derived.get(qkey)
-            if quant is None:
-                quant = dict(qpts=_pad_rows(qpts, pad, 0))
-                if qscale is not None:
-                    quant["qscale"] = _pad_rows(qscale, pad, 1.0)
-                stk._derived[qkey] = quant
-    if pad == 0:
-        return dict(pts=pts, ids=stk.ids, rx=stk.rx, xc=stk.xc,
-                    xs=stk.xs, leaf_centers=stk.leaf_centers,
-                    leaf_radii=stk.leaf_radii, leaf_cnorm=stk.leaf_cnorm,
-                    valid=stk.valid, n_leaves=stk.n_leaves, **quant), Np
-    gkey = f"geom:bucket:{Np}:{'lane' if use_kernel else 'raw'}"
-    geom = stk._derived.get(gkey)
-    if geom is None:
-        geom = dict(pts=_pad_rows(pts, pad, 0.0),
-                    rx=_pad_rows(stk.rx, pad, -1.0),
-                    xc=_pad_rows(stk.xc, pad, 0.0),
-                    xs=_pad_rows(stk.xs, pad, 0.0),
-                    leaf_centers=_pad_rows(stk.leaf_centers, pad, 0.0),
-                    leaf_radii=_pad_rows(stk.leaf_radii, pad, 0.0),
-                    leaf_cnorm=_pad_rows(stk.leaf_cnorm, pad, 0.0))
-        stk._derived[gkey] = geom
-    lkey = f"bucket:{Np}:ids"
-    live = stk._derived.get(lkey)
+        qpts, qscale = stk.quantized_pts(probe_dtype, lane_pad=use_kernel)
+        quant = dict(qpts=qpts)
+        if qscale is not None:
+            quant["qscale"] = qscale
+    geom = dict(pts=pts, rx=stk.rx, xc=stk.xc, xs=stk.xs,
+                leaf_centers=stk.leaf_centers, leaf_radii=stk.leaf_radii,
+                leaf_cnorm=stk.leaf_cnorm)
+    live = dict(ids=stk.ids, valid=stk.valid, n_leaves=stk.n_leaves)
+    if Np == N and L == stk.num_tiles:
+        return {**geom, **live, **quant}, Np
+
+    def padded(key, planes):
+        hit = stk._derived.get(key)
+        if hit is None:
+            hit = {f: _pad_plane(f, a, Np, L) for f, a in planes.items()}
+            stk._derived[key] = hit
+        return hit
+
+    shape = f"{Np}:{L}"
+    if quant:
+        quant = padded(f"geom:quant:bucket:{shape}:{probe_dtype}:{tag}",
+                       quant)
+    return {**padded(f"geom:bucket:{shape}:{tag}", geom),
+            **padded(f"bucket:{shape}:ids", live), **quant}, Np
+
+
+@dataclasses.dataclass(frozen=True)
+class PlacedStacks:
+    """The stacks of a placed sharded index: ``stacks[j]`` lives on
+    device ``j`` of ``mesh`` along ``axis`` (None: that shard has no
+    segment).  The mesh launch takes each device's own stack as its
+    block of the segment axis, padded where it lives to one common
+    segment count and tile count, so no plane moves between devices.
+    Segment order is shard by shard; ``num_segments`` counts the real
+    ones."""
+
+    stacks: tuple
+    mesh: object
+    axis: str = "shard"
+
+    @property
+    def present(self) -> list:
+        return [s for s in self.stacks if s is not None]
+
+    @property
+    def n0(self) -> int:
+        return self.present[0].n0
+
+    @property
+    def d(self) -> int:
+        return self.present[0].d
+
+    @property
+    def num_segments(self) -> int:
+        return sum(s.num_segments for s in self.present)
+
+    @property
+    def num_tiles(self) -> int:
+        return max(s.num_tiles for s in self.present)
+
+    @property
+    def block_rows(self) -> int:
+        """Segment rows of every device's block."""
+        return _bucket_segments(max(s.num_segments for s in self.present))
+
+    def arrays(self, *, use_kernel: bool, probe_dtype: str = "f32"):
+        """``(arrays, Np, true_row, seg_shard, rows)``: the launch's
+        planes as mesh arrays sharded along :attr:`axis`, assembled from
+        the per-device blocks; the padded row count; the (Np,) masks of
+        real rows and of each row's shard (-1 = pad); and the real rows'
+        positions, in stack order."""
+        devices = list(np.asarray(self.mesh.devices).flat)
+        assert len(devices) == len(self.stacks), (devices, self.stacks)
+        nl, L = self.block_rows, self.num_tiles
+        blocks = []
+        for dev, stk in zip(devices, self.stacks):
+            if stk is None:
+                blocks.append(self._dead_block(dev, nl, L, use_kernel,
+                                               probe_dtype))
+            else:
+                blocks.append(_bucketed_arrays(
+                    stk, use_kernel=use_kernel, probe_dtype=probe_dtype,
+                    rows=nl, tiles=L)[0])
+        arrays = {}
+        for f in blocks[0]:
+            local = [b[f] for b in blocks]
+            sharding = NamedSharding(
+                self.mesh, _P(self.axis, *(None,) * (local[0].ndim - 1)))
+            arrays[f] = jax.make_array_from_single_device_arrays(
+                (len(local) * nl,) + local[0].shape[1:], sharding, local)
+        counts = [0 if s is None else s.num_segments for s in self.stacks]
+        true_row = np.zeros((len(counts) * nl,), bool)
+        seg_shard = np.full((len(counts) * nl,), -1, np.int32)
+        for j, n in enumerate(counts):
+            true_row[j * nl:j * nl + n] = True
+            seg_shard[j * nl:j * nl + n] = j
+        return (arrays, len(counts) * nl, true_row, seg_shard,
+                np.nonzero(true_row)[0])
+
+    def _dead_block(self, dev, nl, L, use_kernel, probe_dtype):
+        """An all-pad block made on ``dev`` (a shard with no segment)."""
+        n0, d = self.n0, self.d
+        dp = _ceil_to(d, _LANE) if use_kernel else d
+        shapes = dict(pts=((nl, L, n0, dp), jnp.float32),
+                      ids=((nl, L, n0), jnp.int32),
+                      rx=((nl, L, n0), jnp.float32),
+                      xc=((nl, L, n0), jnp.float32),
+                      xs=((nl, L, n0), jnp.float32),
+                      leaf_centers=((nl, L, d), jnp.float32),
+                      leaf_radii=((nl, L), jnp.float32),
+                      leaf_cnorm=((nl, L, 1), jnp.float32),
+                      valid=((nl, L), jnp.bool_),
+                      n_leaves=((nl,), jnp.int32))
+        if probe_dtype != "f32":
+            shapes["qpts"] = ((nl, L, n0, dp),
+                              jnp.bfloat16 if probe_dtype == "bf16"
+                              else jnp.int8)
+            if probe_dtype == "int8":
+                shapes["qscale"] = ((nl, L, 1), jnp.float32)
+        return {f: jnp.full(shape, _FILL[f], dtype, device=dev)
+                for f, (shape, dtype) in shapes.items()}
+
+
+def _live_tiles(stk) -> np.ndarray:
+    """(N,) tiles holding a live point, per real segment (memoized per
+    stack: ids-derived, so dropped by ids-plane rewrites)."""
+    if isinstance(stk, PlacedStacks):
+        return np.concatenate([_live_tiles(s) for s in stk.present])
+    live = stk._derived.get("live_tiles")
     if live is None:
-        live = dict(ids=_pad_rows(stk.ids, pad, -1),
-                    valid=_pad_rows(stk.valid, pad, False),
-                    n_leaves=_pad_rows(stk.n_leaves, pad, 0))
-        stk._derived[lkey] = live
-    return {**geom, **live, **quant}, Np
+        live = np.asarray(stk.valid).sum(axis=1).astype(np.int64)
+        stk._derived["live_tiles"] = live
+    return live
 
 
 #: arrays-dict fields whose pad/placement rides tombstone republishes
@@ -1711,8 +1853,11 @@ def _call_run_stacked(stk: StackedLeaves, queries, k, *, frac, bq,
                       sort_planes=True, mesh=None, mesh_axis="shard",
                       _warm=False):
     use_kernel, interpret = resolve_stacked_backend(use_kernel, interpret)
+    placed = isinstance(stk, PlacedStacks)
+    if placed:
+        mesh, mesh_axis = stk.mesh, stk.axis
     D = _mesh_axis_size(mesh, mesh_axis)
-    if D <= 1:
+    if D <= 1 and not placed:
         mesh = None  # a 1-device (or axis-less) mesh IS the single
         #              launch -- run the plain program, share its traces
         D = 0
@@ -1720,19 +1865,29 @@ def _call_run_stacked(stk: StackedLeaves, queries, k, *, frac, bq,
                             route=probe_route)
     pdt = resolve_probe_dtype(probe_dtype, p)
     N = stk.num_segments
-    arrays, Np = _bucketed_arrays(stk, use_kernel=bool(use_kernel),
-                                  multiple=(D if mesh is not None else 1),
-                                  probe_dtype=pdt)
-    if mesh is not None:
-        arrays = _placed_arrays(stk, arrays, Np, mesh, mesh_axis,
-                                bool(use_kernel), probe_dtype=pdt)
-    bounds = tuple(int(x) for x in shard_bounds) if shard_bounds else ()
-    num_shards = len(bounds)
-    seg_shard = np.full((Np,), -1, np.int32)
-    if bounds:
-        assert sum(bounds) == N, (bounds, N)
-        seg_shard[:N] = np.repeat(
-            np.arange(num_shards, dtype=np.int32), bounds)
+    rows = None
+    if placed:
+        # every block already sits on its device: only the per-device
+        # pads are made, where the block lives
+        arrays, Np, true_row, seg_shard, rows = stk.arrays(
+            use_kernel=bool(use_kernel), probe_dtype=pdt)
+        num_shards = len(stk.stacks)
+    else:
+        arrays, Np = _bucketed_arrays(
+            stk, use_kernel=bool(use_kernel),
+            multiple=(D if mesh is not None else 1), probe_dtype=pdt)
+        if mesh is not None:
+            arrays = _placed_arrays(stk, arrays, Np, mesh, mesh_axis,
+                                    bool(use_kernel), probe_dtype=pdt)
+        bounds = (tuple(int(x) for x in shard_bounds) if shard_bounds
+                  else ())
+        num_shards = len(bounds)
+        seg_shard = np.full((Np,), -1, np.int32)
+        if bounds:
+            assert sum(bounds) == N, (bounds, N)
+            seg_shard[:N] = np.repeat(
+                np.arange(num_shards, dtype=np.int32), bounds)
+        true_row = np.arange(Np) < N
     has_extra = extra_d is not None
     q2 = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
     B = int(q2.shape[0])
@@ -1762,7 +1917,8 @@ def _call_run_stacked(stk: StackedLeaves, queries, k, *, frac, bq,
     out = runner(arrays, q2, lambda_cap,
                  extra_d if has_extra else None,
                  extra_i if has_extra else None,
-                 jnp.asarray(seg_shard), np.int32(N),
+                 jnp.asarray(seg_shard),
+                 np.int32(N) if mesh is None else jnp.asarray(true_row),
                  n0=stk.n0, d=stk.d, k=k, frac=frac, bq=bq,
                  use_ball=use_ball, use_cone=use_cone,
                  use_kernel=bool(use_kernel),
@@ -1771,7 +1927,8 @@ def _call_run_stacked(stk: StackedLeaves, queries, k, *, frac, bq,
                  has_extra=has_extra, sort_planes=sort_planes)
     if Np != N:  # per-segment outputs slice back to the true rows
         bd, bi, fd, fi, counters, seg_skips, shard_kth, launch_counts = out
-        out = (bd[:N], bi[:N], fd, fi, counters, seg_skips[:N],
+        take = slice(0, N) if rows is None else rows
+        out = (bd[take], bi[take], fd, fi, counters, seg_skips[take],
                shard_kth, launch_counts)
     return out, p, pdt
 
@@ -1909,10 +2066,7 @@ def stacked_sweep_query(stk: StackedLeaves, queries, k: int = 1, *,
     B = int(np.atleast_2d(np.asarray(queries)).shape[0])
     nqb = -(-B // bq)
     n_visit = _n_visit(stk, frac)
-    live = stk._derived.get("live_tiles")  # (N,) -- ids-derived, so the
-    if live is None:  # cache is dropped by ids-plane rewrites
-        live = np.asarray(stk.valid).sum(axis=1).astype(np.int64)
-        stk._derived["live_tiles"] = live
+    live = _live_tiles(stk)
     forced = nqb * np.maximum(0, n_visit - live)  # invalid tiles visited
     probe_scanned = int(stk.num_segments * nqb * p) - probe_skips
     info = {
@@ -1921,6 +2075,7 @@ def stacked_sweep_query(stk: StackedLeaves, queries, k: int = 1, *,
         "shard_kth": shard_kth,
         "probe": {"tiles": p, "scanned": probe_scanned,
                   "skipped": probe_skips, "dtype": pdt},
-        "mesh_devices": max(1, _mesh_axis_size(mesh, mesh_axis)),
+        "mesh_devices": (len(stk.stacks) if isinstance(stk, PlacedStacks)
+                         else max(1, _mesh_axis_size(mesh, mesh_axis))),
     }
     return fd, fi, counters, info

@@ -44,14 +44,20 @@ def _delta_topk(points, gids, queries, k: int):
     return bd, bi
 
 
-def delta_topk(points: np.ndarray, gids: np.ndarray, queries, k: int):
+def delta_topk(points: np.ndarray, gids: np.ndarray, queries, k: int, *,
+               device=None):
     """Exact top-k over the delta rows; (dists (B,k), gids (B,k)).
     The host block is copied to the device whole, on every call: span
     ``p2h.delta.upload`` bounds the host call that starts the copy (on a
     TPU it returns before the transfer lands, and the device waits for
-    it later), counter ``delta_upload_bytes`` its bytes."""
+    it later), counter ``delta_upload_bytes`` its bytes.  ``device``
+    places the block (and the scan) on that device; None keeps JAX's
+    default placement."""
     with spans.span("p2h.delta.upload"):
-        points_dev, gids_dev = jnp.asarray(points), jnp.asarray(gids)
+        if device is None:
+            points_dev, gids_dev = jnp.asarray(points), jnp.asarray(gids)
+        else:
+            points_dev, gids_dev = jax.device_put((points, gids), device)
     spans.count("delta_upload_bytes", points.nbytes + gids.nbytes)
     return _delta_topk(points_dev, gids_dev, jnp.asarray(queries), k)
 
@@ -89,6 +95,17 @@ class DeltaBuffer:
         self.gids[row] = gid
         self.length += 1
         return row
+
+    def extend(self, points: np.ndarray, gids: np.ndarray) -> np.ndarray:
+        """Assign the next ``len(points)`` rows (they must fit); returns
+        their row indices."""
+        m = len(points)
+        assert self.length + m <= self.capacity, "delta buffer overflow"
+        rows = np.arange(self.length, self.length + m)
+        self.points[rows] = points
+        self.gids[rows] = gids
+        self.length += m
+        return rows
 
     def tombstone(self, row: int) -> None:
         self.gids[row] = -1

@@ -80,8 +80,12 @@ class MutableP2HIndex:
 
     def __init__(self, dim: int, *, n0: int = 128, variant: str = "bc",
                  policy: CompactionPolicy | None = None, seed: int = 0,
-                 background: bool = False):
+                 background: bool = False, device=None):
         assert variant in ("ball", "bc"), variant
+        #: the device every device-side piece of this index is made on
+        #: (sealed segments' sweep views, placed at publish; stacked
+        #: planes; the delta upload); None = JAX's default placement
+        self.device = device
         self.dim = int(dim)  # raw point dimensionality
         self.d = self.dim + 1  # with the appended 1-coordinate
         self.n0 = int(n0)
@@ -215,10 +219,19 @@ class MutableP2HIndex:
         out = np.empty((len(pts),), np.int32)
         with spans.span("p2h.write.insert"):
             with self._lock:
-                for i, x in enumerate(pts):
-                    out[i] = self._insert_one_locked(
-                        x, gid=None if gids is None else int(gids[i]))
-                    self._wal_log_insert(pts[i], int(out[i]))
+                # rows go in as one block per delta fill; a full delta is
+                # flushed before the next block, as before each row
+                i = 0
+                while i < len(pts):
+                    self._make_room_locked()
+                    m = min(len(pts) - i,
+                            self._delta.capacity - self._delta.length)
+                    out[i:i + m] = self._append_rows_locked(
+                        pts[i:i + m], None if gids is None else gids[i:i + m])
+                    if self._wal is not None:
+                        for j in range(i, i + m):
+                            self._wal_log_insert(pts[j], int(out[j]))
+                    i += m
                 self._publish()
                 self._maybe_compact_locked()
             self._wal_commit()
@@ -229,6 +242,49 @@ class MutableP2HIndex:
         """Append one point to the delta (compacting if full); no
         publish -- callers publish once per API call."""
         x1 = np.concatenate([x, np.ones((1,), np.float32)])
+        self._make_room_locked()
+        if gid is None:
+            gid = self._next_gid
+            self._next_gid += 1
+        else:
+            gid = int(gid)
+            assert gid not in self._locator, f"gid {gid} already live"
+            self._next_gid = max(self._next_gid, gid + 1)
+        row = self._delta.append(x1, gid)
+        self._locator[gid] = ("delta", id(self._delta), row)
+        self._live_count += 1
+        self._max_norm = max(self._max_norm, float(np.linalg.norm(x1)))
+        return gid
+
+    def _append_rows_locked(self, x: np.ndarray, gids) -> np.ndarray:
+        """:meth:`_insert_one_locked` for rows ``x`` that fit the delta's
+        free rows, as one block; returns their gids.  The max norm is
+        taken row by row (``sqrt(x1 . x1)``, as ``np.linalg.norm`` of one
+        row), so it is the per-row insert's to the bit."""
+        m = len(x)
+        x1 = np.concatenate([x, np.ones((m, 1), np.float32)], axis=1)
+        if gids is None:
+            g = np.arange(self._next_gid, self._next_gid + m, dtype=np.int64)
+            self._next_gid += m
+        else:
+            g = np.asarray(gids, np.int64)
+            for gid in g.tolist():
+                assert gid not in self._locator, f"gid {gid} already live"
+            assert len(set(g.tolist())) == m, "gids repeat within a batch"
+            self._next_gid = max(self._next_gid, int(g.max()) + 1)
+        rows = self._delta.extend(x1, g)
+        where = id(self._delta)
+        self._locator.update(
+            (gid, ("delta", where, r))
+            for gid, r in zip(g.tolist(), rows.tolist()))
+        self._live_count += m
+        self._max_norm = max(self._max_norm, float(np.sqrt(max(
+            r.dot(r) for r in x1))))
+        return g
+
+    def _make_room_locked(self) -> None:
+        """Flush (or, in background mode, seal) a full delta so the next
+        row has a place."""
         while self._delta.full:
             self._raise_compact_errors_locked()  # don't spin forever
             if self._background:
@@ -249,18 +305,6 @@ class MutableP2HIndex:
                     self._cond.wait(timeout=1.0)  # compactor republishes
             else:
                 self._compact_locked(self._plan_locked())
-        if gid is None:
-            gid = self._next_gid
-            self._next_gid += 1
-        else:
-            gid = int(gid)
-            assert gid not in self._locator, f"gid {gid} already live"
-            self._next_gid = max(self._next_gid, gid + 1)
-        row = self._delta.append(x1, gid)
-        self._locator[gid] = ("delta", id(self._delta), row)
-        self._live_count += 1
-        self._max_norm = max(self._max_norm, float(np.linalg.norm(x1)))
-        return gid
 
     def delete(self, gid: int, *, commit: bool = True) -> bool:
         """Delete by global id; returns False if the id is not live.
@@ -746,7 +790,7 @@ class MutableP2HIndex:
             prepub = dict(stacked=None, sources=None, locator=None,
                           warmed=0)
             if segs:
-                stk = StackedLeaves.from_segments(segs)
+                stk = StackedLeaves.from_segments(segs, device=self.device)
                 prepub.update(stacked=stk, sources=tuple(segs))
                 hook = self._warmup_hook
                 if hook is None:
@@ -768,8 +812,12 @@ class MutableP2HIndex:
                 # own shape-keyed program; warm it for the new tree too,
                 # or the first post-publish exchange compiles on-path
                 from repro.core.distributed import warm_round1
+                tree = built.tree
+                if self.device is not None:
+                    tree, _ = built.resident_tree(self.device,
+                                                  on_path=False)
                 prepub["warmed"] += warm_round1(
-                    built.tree, is_bc=(self.variant == "bc"))
+                    tree, is_bc=(self.variant == "bc"))
                 pid = np.asarray(built.tree.point_ids)
                 prepub["locator"] = {
                     int(built.gids[local]): ("seg", built.uid, int(local))
@@ -842,6 +890,7 @@ class MutableP2HIndex:
             variant=self.variant,
             n0=self.n0,
             d=self.d,
+            device=self.device,
         )
 
     def _publish(self, prepub: dict | None = None) -> None:
@@ -860,6 +909,9 @@ class MutableP2HIndex:
             self._epoch += 1
             prev = self._snapshot
             snap = self._make_snapshot()
+            if self.device is not None:
+                for seg in snap.segments:
+                    seg.place(self.device)
             snap.adopt_stacked_from(prev)
             if prepub is not None and prepub.get("stacked") is not None:
                 snap.adopt_prebuilt_stacked(prepub["stacked"],

@@ -56,6 +56,14 @@ Composition:
     (atomic JSON + ``OP_ROUTER`` WAL records) so a crash mid-migration
     recovers to a consistent map with every gid owned exactly once.
 
+Placement: ``devices=`` (one device per shard) pins shard ``s`` to
+``devices[s]`` for the index's lifetime.  Everything device-side of the
+shard is made there -- its segments' sweep views (placed once per
+publish; round 1 reads them), its stacked planes and their derived
+copies, its delta upload -- and the serving mesh over those devices
+runs round 2 on each device's own stack, so no plane crosses between
+devices.  Without it every shard uses JAX's default device.
+
 Thread model: per-shard writer locks only -- there is no global write
 lock.  Gid allocation is the single cross-shard synchronization point
 (one counter behind a mutex); deletes additionally hold the migration
@@ -66,6 +74,7 @@ the shard count.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
 import os
@@ -146,6 +155,19 @@ _ROUTER_KINDS = {HashRouter.kind: HashRouter,
 _WAL_NAME = re.compile(r"shard_(\d+)\.wal")
 
 
+def _each_shard(calls, *, parallel: bool) -> None:
+    """Run per-shard ``calls``, each on its own thread when ``parallel``;
+    the first error raises here."""
+    calls = list(calls)
+    if not parallel or len(calls) < 2:
+        for call in calls:
+            call()
+        return
+    with concurrent.futures.ThreadPoolExecutor(len(calls)) as pool:
+        for fut in [pool.submit(call) for call in calls]:
+            fut.result()
+
+
 def _count_wal_shards(wal_dir: str) -> int:
     """Number of shards a WAL directory's logs imply (0 if none)."""
     if not os.path.isdir(wal_dir):
@@ -166,7 +188,8 @@ class ShardedMutableP2HIndex:
                  seed: int = 0, background: bool = False, router: Any = None,
                  shards: tuple | None = None, wal_dir: str | None = None,
                  wal_config: WalConfig | None = None,
-                 on_ack: Any = None, ckpt_root: str | None = None):
+                 on_ack: Any = None, ckpt_root: str | None = None,
+                 devices: Any = None):
         self.dim = int(dim)
         self.d = self.dim + 1
         self.num_shards = int(num_shards)
@@ -203,6 +226,19 @@ class ShardedMutableP2HIndex:
             if journal is not None:
                 self.num_shards = max(self.num_shards,
                                       max(journal.assignment) + 1)
+        #: serving devices, shard ``s`` on ``devices[s]`` (None = JAX's
+        #: default placement; see the class docstring's *Placement*)
+        self.devices = None
+        if devices is not None:
+            from jax.sharding import Mesh
+
+            self.devices = tuple(devices)
+            if len(self.devices) != self.num_shards or shards is not None:
+                raise ValueError(
+                    f"devices= places a new index's {self.num_shards} "
+                    f"shards one per device; got {len(self.devices)}")
+            self._mesh = Mesh(np.asarray(self.devices, dtype=object),
+                              (self._mesh_axis,))
         self.router = router or HashRouter(self.num_shards)
         if shards is not None:  # load() supplies restored shards
             assert len(shards) == self.num_shards
@@ -212,7 +248,9 @@ class ShardedMutableP2HIndex:
             self.shards = tuple(
                 MutableP2HIndex(dim, n0=n0, variant=variant,
                                 policy=self.policy, seed=seed + 1000 * s,
-                                background=background)
+                                background=background,
+                                device=(None if self.devices is None
+                                        else self.devices[s]))
                 for s in range(self.num_shards))
         self._gid_lock = threading.Lock()
         self._next_gid = max((sh._next_gid for sh in self.shards),
@@ -281,10 +319,11 @@ class ShardedMutableP2HIndex:
         self = cls(data.shape[1], num_shards, **kw)
         gids = np.arange(len(data), dtype=np.int64)
         owner = self._owners(gids)
-        for s, shard in enumerate(self.shards):
-            mask = owner == s
-            if mask.any():
-                shard.bulk_seed(data[mask], gids=gids[mask])
+        _each_shard(
+            (functools.partial(shard.bulk_seed, data[owner == s],
+                               gids=gids[owner == s])
+             for s, shard in enumerate(self.shards) if (owner == s).any()),
+            parallel=True)
         with self._gid_lock:
             self._next_gid = len(data)
         return self
@@ -322,11 +361,16 @@ class ShardedMutableP2HIndex:
         pts = np.atleast_2d(np.asarray(points, np.float32))
         gids = self._alloc_gids(len(pts))
         owner = self._owners(gids)
-        for s in range(len(self.shards)):
-            mask = owner == s
-            if mask.any():
-                self.shards[s].insert_batch(pts[mask], gids=gids[mask])
-                self._fix_stragglers(gids[mask], s)
+        routed = [(s, owner == s) for s in range(len(self.shards))]
+        routed = [(s, mask) for s, mask in routed if mask.any()]
+        # a batch that can fill a delta seals segments: build the shards'
+        # trees at the same time (numpy releases the GIL)
+        _each_shard(
+            (functools.partial(self.shards[s].insert_batch, pts[mask],
+                               gids=gids[mask]) for s, mask in routed),
+            parallel=len(pts) >= self.policy.delta_capacity)
+        for s, mask in routed:
+            self._fix_stragglers(gids[mask], s)
         return gids.astype(np.int32)
 
     def _fix_stragglers(self, gids, owner: int) -> None:
@@ -400,7 +444,18 @@ class ShardedMutableP2HIndex:
         that topology -- placing the post-compaction stack's planes on
         their owning devices *before* the publish flips the epoch.
         Build meshes with :func:`repro.launch.mesh.make_serving_mesh`;
-        answers are bit-identical with or without one."""
+        answers are bit-identical with or without one.
+
+        This is the mesh of an index built without ``devices=``: its
+        shards' stacks live on the default device, and round 2
+        concatenates them there and splits the segment axis evenly over
+        the mesh, whatever the shard count (e.g. 2 shards over 4 chips).
+        An index built with ``devices=`` serves over the mesh of those
+        devices for its lifetime, so it refuses another."""
+        if self.devices is not None:
+            raise ValueError(
+                "a placed index (devices=) serves over the mesh of its "
+                "own devices; set_mesh is for an index built without it")
         self._mesh = mesh
         self._mesh_axis = str(axis)
 
@@ -416,7 +471,8 @@ class ShardedMutableP2HIndex:
         caller swallows exceptions); other shards may republish before
         the flip, in which case this warms a stale-but-bucketed shape
         and the miss falls back to query-path compile as before."""
-        from repro.kernels.stacked_sweep import concat_cached, warm_stacked
+        from repro.kernels.stacked_sweep import (PlacedStacks, concat_cached,
+                                                 warm_stacked)
 
         stks = []
         for s, sh in enumerate(self.shards):
@@ -424,10 +480,15 @@ class ShardedMutableP2HIndex:
                 stks.append(prebuilt_stk)
                 continue
             snap = sh.snapshot()
-            if snap.segments:
-                stks.append(snap.stacked_leaves())
-        if stks:
-            warm_stacked(concat_cached(stks))
+            stks.append(snap.stacked_leaves() if snap.segments else None)
+        present = [stk for stk in stks if stk is not None]
+        if not present:
+            return
+        if self.devices is not None:
+            warm_stacked(PlacedStacks(tuple(stks), self._mesh,
+                                      self._mesh_axis))
+        else:
+            warm_stacked(concat_cached(present))
 
     def admission_stats(self) -> dict:
         """Cross-shard write-admission counters (sums of each shard's

@@ -44,6 +44,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,7 +53,7 @@ from repro.core.balltree import FlatTree
 from repro.runtime import spans
 from repro.stream.delta import delta_topk
 
-__all__ = ["Segment", "Snapshot", "DeltaView", "ShardedSnapshot"]
+__all__ = ["Segment", "Snapshot", "DeltaView", "Pending", "ShardedSnapshot"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,9 +119,7 @@ class Segment:
         """New segment with one point masked out (point_ids row -> -1)."""
         pid = np.array(self.tree.point_ids)  # host copy
         pid[self.row_of_local[local_id]] = -1
-        tree = dataclasses.replace(self.tree, point_ids=pid)
-        return dataclasses.replace(self, tree=tree,
-                                   live=self.live - 1, dead=self.dead + 1)
+        return self._tombstoned(pid, 1)
 
     def with_tombstones(self, local_ids) -> "Segment":
         """Batch form of :meth:`with_tombstone` (one array copy total)."""
@@ -129,10 +128,58 @@ class Segment:
             return self
         pid = np.array(self.tree.point_ids)
         pid[self.row_of_local[local_ids]] = -1
+        return self._tombstoned(pid, int(local_ids.size))
+
+    def _tombstoned(self, pid: np.ndarray, n: int) -> "Segment":
+        """This segment with ids plane ``pid`` and ``n`` more dead rows.
+        A device-resident sweep view rides along: the geometry is shared
+        and only the ids plane is placed again, on first use."""
         tree = dataclasses.replace(self.tree, point_ids=pid)
-        return dataclasses.replace(self, tree=tree,
-                                   live=self.live - int(local_ids.size),
-                                   dead=self.dead + int(local_ids.size))
+        out = dataclasses.replace(self, tree=tree, live=self.live - n,
+                                  dead=self.dead + n)
+        res = (self.__dict__.get("_resident")
+               or self.__dict__.get("_resident_base"))
+        if res is not None:
+            object.__setattr__(out, "_resident_base", res)
+        return out
+
+    def resident_tree(self, device, *, on_path: bool = True):
+        """The segment's sweep view on ``device``: ``(tree, gids)`` with
+        the leaf and point arrays placed there once and kept for the
+        segment's lifetime, so a query never re-sends them.  The node
+        arrays, which no sweep reads, are one-row placeholders and the
+        point count is left out of the static shape, so every segment of
+        a leaf count shares one compiled sweep.
+
+        A tombstoned copy of a placed segment re-sends only its ids
+        plane.  Counters: ``tree_uploads`` per whole placement;
+        ``exchange_upload_bytes`` the bytes placed when ``on_path`` (a
+        query is waiting for them)."""
+        hit = self.__dict__.get("_resident")
+        if hit is not None and hit[0] == device:
+            return hit[1], hit[2]
+        base = self.__dict__.get("_resident_base")
+        pid = np.asarray(self.tree.point_ids)
+        if base is not None and base[0] == device:
+            tree = dataclasses.replace(base[1],
+                                       point_ids=jax.device_put(pid, device))
+            gids, nbytes = base[2], pid.nbytes
+        else:
+            tree, gids, nbytes = _place_sweep_view(self.tree, self.gids,
+                                                   device)
+            spans.count("tree_uploads")
+        if on_path:
+            spans.count("exchange_upload_bytes", nbytes)
+        object.__setattr__(self, "_resident", (device, tree, gids))
+        return tree, gids
+
+    def place(self, device) -> None:
+        """Make the sweep view resident on ``device`` now (publish time)
+        unless it already is, or rides along from a tombstoned ancestor
+        (whose ids plane is then placed at first use)."""
+        base = self.__dict__.get("_resident_base")
+        if base is None or base[0] != device:
+            self.resident_tree(device, on_path=False)
 
     def live_rows(self):
         """(points, gids) of live rows -- compaction input."""
@@ -140,6 +187,28 @@ class Segment:
         rows = np.nonzero(pid >= 0)[0]
         pts = np.asarray(self.tree.points)[rows]
         return pts, self.gids[pid[rows]]
+
+
+def _place_sweep_view(tree: FlatTree, gids, device):
+    """``(tree, gids, bytes)``: ``tree``'s leaf and point arrays and
+    ``gids`` placed on ``device``; node arrays one zero row each, and
+    ``n`` / ``num_nodes`` / ``max_depth`` fixed, so the compiled sweep is
+    keyed on the leaf count alone (``search.sweep_search`` reads no node
+    array)."""
+    leaf = {f: np.asarray(getattr(tree, f))
+            for f in ("leaf_centers", "leaf_radii", "leaf_cnorm", "points",
+                      "point_ids", "rx", "xcos", "xsin")}
+    gids = np.asarray(gids, np.int32)
+    placed, gids_dev = jax.device_put((leaf, gids), device)
+    node = jax.device_put(dict(
+        centers=np.zeros((1, tree.d), np.float32),
+        radii=np.zeros((1,), np.float32), counts=np.zeros((1,), np.int32),
+        left=np.full((1,), -1, np.int32), right=np.full((1,), -1, np.int32),
+        node_leaf=np.zeros((1,), np.int32)), device)
+    view = FlatTree(**placed, **node, n0=tree.n0, n=0, d=tree.d,
+                    num_nodes=1, num_leaves=tree.num_leaves, max_depth=0)
+    nbytes = sum(a.nbytes for a in leaf.values()) + gids.nbytes
+    return view, gids_dev, nbytes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,6 +227,10 @@ class Snapshot:
     variant: str  # "ball" | "bc"
     n0: int
     d: int
+    #: the device this shard's device-side state lives on (the delta
+    #: upload, the segments' sweep views and stacked planes); None =
+    #: JAX's default placement.  Placement, not state.
+    device: Any = dataclasses.field(default=None, compare=False)
 
     # ------------------------------------------------------------------
     @property
@@ -173,7 +246,7 @@ class Snapshot:
         return dead / (live + dead) if live + dead else 0.0
 
     # -- stacked-leaf cache (segment-parallel sweep) -------------------
-    def stacked_leaves(self):
+    def stacked_leaves(self, min_tiles: int = 0):
         """The segments stacked into one padded tile grid
         (:class:`repro.kernels.StackedLeaves`), memoized on this
         snapshot: segments are immutable, so stacking is a one-time cost
@@ -184,7 +257,9 @@ class Snapshot:
         plus pending ids-plane diffs travel through publishes as plain
         Python references, so the publish path (and in particular the
         delete path, which republishes per tombstone) never dispatches
-        device work."""
+        device work.  On a placed snapshot the planes are built on its
+        :attr:`device`; ``min_tiles`` (a first build only) pads the tile
+        axis up to a count other shards' stacks share."""
         stk = self.__dict__.get("_stacked")
         if stk is None and self.segments:
             base = self.__dict__.get("_stacked_base")
@@ -194,10 +269,15 @@ class Snapshot:
                     stk = base.with_updated_ids(
                         self.__dict__.get("_stacked_pending") or {})
                 spans.count("ids_rewrites")
+                nbytes = stk.ids.nbytes
             else:
                 from repro.kernels.stacked_sweep import StackedLeaves
 
-                stk = StackedLeaves.from_segments(self.segments)
+                stk = StackedLeaves.from_segments(
+                    self.segments, device=self.device, tiles=min_tiles)
+                nbytes = stk.nbytes
+            if self.device is not None:
+                spans.count("exchange_upload_bytes", nbytes)
             object.__setattr__(self, "_stacked", stk)
         return stk
 
@@ -313,9 +393,43 @@ class Snapshot:
         stacked route consumes it; the sequential walk ignores it.
         Answers are exact on every path; only tile-skip counters differ.
         """
-        q = jnp.asarray(np.atleast_2d(queries), jnp.float32)
+        bd, bi, counters, pending = self._query(
+            queries, k, method=method, frac=frac, lambda_cap=lambda_cap,
+            include_deltas=include_deltas, stacked=stacked,
+            probe_tiles=probe_tiles, probe_dtype=probe_dtype, mesh=mesh,
+            mesh_axis=mesh_axis)
+        bd, bi = np.asarray(bd), np.asarray(bi)
+        for cnt in pending:
+            counters += np.asarray(cnt, np.int64)
+        if return_counters:
+            return bd, bi, counters
+        return bd, bi
+
+    def dispatch(self, queries, k: int = 1, *, method: str = "sweep",
+                 frac: float = 1.0, lambda_cap=None,
+                 include_deltas: bool = True):
+        """:meth:`query` without waiting for the device: the answer stays
+        on the snapshot's device until :meth:`Pending.result` reads it, so
+        a caller can start the same work on several shards' devices
+        before it reads any of them (round 1 of the two-round
+        exchange).  Sequential and beam methods only."""
+        return Pending(*self._query(queries, k, method=method, frac=frac,
+                                    lambda_cap=lambda_cap,
+                                    include_deltas=include_deltas,
+                                    stacked=False))
+
+    def _query(self, queries, k, *, method, frac, lambda_cap,
+               include_deltas, stacked, probe_tiles=None, probe_dtype=None,
+               mesh=None, mesh_axis="shard"):
+        """The body of :meth:`query`: ``(dists, ids)`` as device arrays,
+        the host counters so far and the device counters still to add."""
+        if isinstance(queries, jax.Array) and queries.ndim == 2:
+            q = queries.astype(jnp.float32)  # stays where it was placed
+        else:
+            q = jnp.asarray(np.atleast_2d(queries), jnp.float32)
         B = q.shape[0]
         counters = np.zeros((8,), np.int64)
+        pending = []
 
         if include_deltas:
             bd, bi, nver = self.delta_candidates(q, k)
@@ -348,22 +462,23 @@ class Snapshot:
                     cap = bd[:, k - 1]  # running merged k-th: a valid cap
                     if ext is not None:
                         cap = jnp.minimum(cap, ext)
-                sd, si, cnt = _segment_query(seg.tree, q, k, method=method,
+                if self.device is None:
+                    tree, gids = seg.tree, jnp.asarray(seg.gids)
+                else:
+                    tree, gids = seg.resident_tree(self.device)
+                sd, si, cnt = _segment_query(tree, q, k, method=method,
                                              frac=frac,
                                              variant=self.variant,
                                              lambda_cap=cap)
                 sg = jnp.where(si >= 0,
-                               jnp.take(jnp.asarray(seg.gids),
+                               jnp.take(gids,
                                         jnp.clip(si, 0, len(seg.gids) - 1)),
                                -1)
                 bd, bi = search.merge_topk(
                     jnp.concatenate([bd, sd], axis=1),
                     jnp.concatenate([bi, sg], axis=1), k)
-                counters += np.asarray(cnt, np.int64)
-        bd, bi = np.asarray(bd), np.asarray(bi)
-        if return_counters:
-            return bd, bi, counters
-        return bd, bi
+                pending.append(cnt)
+        return bd, bi, counters, pending
 
     def delta_candidates(self, q, k: int):
         """The delta scan's merged top-k over every delta view -- the
@@ -379,7 +494,11 @@ class Snapshot:
             bi = jnp.full((B, k), -1, jnp.int32)
             verified = 0
             for view in self.deltas:
-                dd, di = delta_topk(view.points, view.gids, q, k)
+                dd, di = delta_topk(view.points, view.gids, q, k,
+                                    device=self.device)
+                if self.device is not None:
+                    spans.count("exchange_upload_bytes",
+                                view.points.nbytes + view.gids.nbytes)
                 bd, bi = search.merge_topk(
                     jnp.concatenate([bd, dd], axis=1),
                     jnp.concatenate([bi, di], axis=1), k)
@@ -424,6 +543,27 @@ class Snapshot:
             use_ball=is_bc, use_cone=is_bc, use_kernel=use_kernel,
             mesh=mesh, mesh_axis=mesh_axis)
         return fd, fi, cnt
+
+
+@dataclasses.dataclass(frozen=True)
+class Pending:
+    """A dispatched :meth:`Snapshot.dispatch`: device answers and the
+    counters still on the device."""
+
+    dists: Any
+    ids: Any
+    counters: np.ndarray
+    device_counters: list
+
+    def result(self):
+        """``(dists, ids, counters)`` on the host (waits for the device)."""
+        with spans.span("p2h.device_wait"):
+            jax.block_until_ready((self.dists, self.ids,
+                                   self.device_counters))
+        counters = self.counters.copy()
+        for cnt in self.device_counters:
+            counters += np.asarray(cnt, np.int64)
+        return np.asarray(self.dists), np.asarray(self.ids), counters
 
 
 @dataclasses.dataclass(frozen=True)

@@ -6,7 +6,9 @@ the (8, 128) tiling, scalar-memory overflow, unsupported ops) surface
 here, at no chip time, in a process held to the CPU.  Shapes are the
 Music-100 deployment's: d + 1 = 101 columns (lane-padded to 128),
 n0 = 128, query blocks of 8, k = 10; the stacked passes also compile at
-SUN397's widths: d + 1 = 513 columns (lane-padded to 640), k = 50.
+SUN397's widths: d + 1 = 513 columns (lane-padded to 640), k = 50, and
+at GIST1M's: d + 1 = 961 (lane-padded to 1,024), k = 10, whose mesh
+launch also compiles across the four described chips.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers import every
@@ -26,12 +28,12 @@ DP, N0, BQ, K = 128, 128, 8, 10
 B = 32  # query rows: 4 blocks of 8
 
 #: (lane-padded width, k) of each deployment the stacked passes serve
-WIDTHS = pytest.mark.parametrize("dp,k", [(DP, K), (640, 50)],
-                                 ids=["music100", "sun397"])
+WIDTHS = pytest.mark.parametrize("dp,k", [(DP, K), (640, 50), (1024, K)],
+                                 ids=["music100", "sun397", "gist"])
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
@@ -43,8 +45,13 @@ def one_chip():
     # persistent cache without one: keep the cache out of it
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -108,3 +115,39 @@ def test_stacked_sweep_bf16_probe_pass_compiles(one_chip, dp, k):
     args = _stacked_args(S, N, L, probe, jnp.bfloat16, dp) + (
         S((B, 1)), S((N, L, 1)), S((N, L, 1)))
     _compiled_kernel(probe_pass, args)
+
+
+def test_placed_mesh_launch_compiles_on_four_chips(topo):
+    """Round 2 of a placed four-shard index at GIST1M's widths: each
+    chip's block of 4 segments, its own kernel, one all-gather."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.stacked_sweep import _run_stacked_mesh
+
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    Np, L, d, dp = 16, 64, 961, 1024
+
+    def S(shape, dtype=jnp.float32, replicated=False):
+        spec = P() if replicated else P("shard", *(None,) * (len(shape) - 1))
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    arrays = dict(pts=S((Np, L, N0, dp)), ids=S((Np, L, N0), jnp.int32),
+                  rx=S((Np, L, N0)), xc=S((Np, L, N0)), xs=S((Np, L, N0)),
+                  leaf_centers=S((Np, L, d)), leaf_radii=S((Np, L)),
+                  leaf_cnorm=S((Np, L, 1)), valid=S((Np, L), jnp.bool_),
+                  n_leaves=S((Np,), jnp.int32))
+    compiled = _run_stacked_mesh.lower(
+        arrays, S((BQ, d), replicated=True), S((BQ,), replicated=True),
+        None, None, S((Np,), jnp.int32, replicated=True),
+        S((Np,), jnp.bool_, replicated=True), mesh=mesh, mesh_axis="shard",
+        n0=N0, d=d, k=K, frac=1.0, bq=BQ, use_ball=True, use_cone=True,
+        use_kernel=True, interpret=False, probe_tiles=0, probe_dtype="f32",
+        num_shards=4, has_extra=False, sort_planes=False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    # each chip holds its own quarter of the points plane
+    assert compiled.memory_analysis().argument_size_in_bytes < (
+        Np * L * N0 * dp * 4) // 2
