@@ -8,11 +8,13 @@ construction is roughly linear and 1-3 orders of magnitude cheaper than
 the hashing baselines' indexing -- which makes *rebuild* a viable update
 primitive:
 
-``DeltaBuffer`` (delta.py)
+``DeltaBuffer`` / ``DeltaView`` (delta.py)
     The memtable.  Inserts append to a fixed-capacity host buffer,
-    queried by an exact brute-force scan jitted on the static capacity.
+    queried by an exact brute-force scan jitted on the static capacity;
+    a ``DeltaView`` is its frozen form in a snapshot, and the scan reads
+    a device copy the buffer keeps, sent only the rows appended since.
 
-``Segment`` / ``Snapshot`` / ``DeltaView`` (snapshot.py)
+``Segment`` / ``Snapshot`` (snapshot.py)
     Sealed ``FlatTree`` segments with global-id tables; deletes mask a
     point's ``point_ids`` row to -1 (the backends' existing pad
     convention, so every bound stays valid).  A ``Snapshot`` is an

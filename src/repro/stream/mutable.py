@@ -49,7 +49,7 @@ from repro.core.balltree import append_ones, normalize_query
 from repro.runtime import spans
 from repro.stream.compaction import CompactionPlan, CompactionPolicy
 from repro.stream.delta import DeltaBuffer
-from repro.stream.snapshot import DeltaView, Segment, Snapshot
+from repro.stream.snapshot import Segment, Snapshot
 
 __all__ = ["MutableP2HIndex"]
 
@@ -84,7 +84,7 @@ class MutableP2HIndex:
         assert variant in ("ball", "bc"), variant
         #: the device every device-side piece of this index is made on
         #: (sealed segments' sweep views, placed at publish; stacked
-        #: planes; the delta upload); None = JAX's default placement
+        #: planes; the delta's device copy); None = JAX's default placement
         self.device = device
         self.dim = int(dim)  # raw point dimensionality
         self.d = self.dim + 1  # with the appended 1-coordinate
@@ -878,8 +878,8 @@ class MutableP2HIndex:
             return uid
 
     def _make_snapshot(self) -> Snapshot:
-        views = [DeltaView(*self._delta.frozen_view())]
-        views += [DeltaView(*b.frozen_view()) for b in self._sealed]
+        views = [self._delta.frozen_view()]
+        views += [b.frozen_view() for b in self._sealed]
         return Snapshot(
             epoch=self._epoch,
             last_delete_epoch=self._last_delete_epoch,

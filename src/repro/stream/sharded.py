@@ -60,7 +60,7 @@ Placement: ``devices=`` (one device per shard) pins shard ``s`` to
 ``devices[s]`` for the index's lifetime.  Everything device-side of the
 shard is made there -- its segments' sweep views (placed once per
 publish; round 1 reads them), its stacked planes and their derived
-copies, its delta upload -- and the serving mesh over those devices
+copies, its delta's device copy -- and the serving mesh over those devices
 runs round 2 on each device's own stack, so no plane crosses between
 devices.  Without it every shard uses JAX's default device.
 

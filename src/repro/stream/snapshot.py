@@ -51,27 +51,9 @@ import numpy as np
 from repro.core import search
 from repro.core.balltree import FlatTree
 from repro.runtime import spans
-from repro.stream.delta import delta_topk
+from repro.stream.delta import DeltaView, delta_topk
 
 __all__ = ["Segment", "Snapshot", "DeltaView", "Pending", "ShardedSnapshot"]
-
-
-@dataclasses.dataclass(frozen=True)
-class DeltaView:
-    """Frozen view of one delta buffer (active or sealed-for-compaction).
-
-    ``points`` is the buffer's shared append-only block -- rows past
-    ``length`` were unassigned at freeze time and their ``gids`` entries
-    are -1 in the frozen copy, so later appends are invisible here.
-    """
-
-    points: np.ndarray  # (C, d) shared
-    gids: np.ndarray  # (C,) frozen copy, -1 = empty/deleted
-    length: int
-
-    @property
-    def live(self) -> int:
-        return int((self.gids >= 0).sum())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,8 +209,8 @@ class Snapshot:
     variant: str  # "ball" | "bc"
     n0: int
     d: int
-    #: the device this shard's device-side state lives on (the delta
-    #: upload, the segments' sweep views and stacked planes); None =
+    #: the device this shard's device-side state lives on (the delta's
+    #: device copy, the segments' sweep views and stacked planes); None =
     #: JAX's default placement.  Placement, not state.
     device: Any = dataclasses.field(default=None, compare=False)
 
@@ -494,11 +476,7 @@ class Snapshot:
             bi = jnp.full((B, k), -1, jnp.int32)
             verified = 0
             for view in self.deltas:
-                dd, di = delta_topk(view.points, view.gids, q, k,
-                                    device=self.device)
-                if self.device is not None:
-                    spans.count("exchange_upload_bytes",
-                                view.points.nbytes + view.gids.nbytes)
+                dd, di = delta_topk(view, q, k, device=self.device)
                 bd, bi = search.merge_topk(
                     jnp.concatenate([bd, dd], axis=1),
                     jnp.concatenate([bi, di], axis=1), k)

@@ -60,8 +60,8 @@ _BODY = textwrap.dedent(
     seen = []
     real_delta_topk = snapmod.delta_topk
 
-    def spy(points, gids, q, k, *, device=None):
-        out = real_delta_topk(points, gids, q, k, device=device)
+    def spy(view, q, k, *, device=None):
+        out = real_delta_topk(view, q, k, device=device)
         seen.append((str(device), sorted(map(str, out[0].devices()))))
         return out
 
@@ -84,10 +84,10 @@ _BODY = textwrap.dedent(
     report["spans"] = {n: v["count"] for n, v in st["spans"].items()
                        if n.startswith("p2h.exchange")}
     report["delta_calls"] = seen
-    report["expected_upload"] = int(
-        sum(v.points.nbytes + v.gids.nbytes
-            for s in snap.shards for v in s.deltas)
-        + 4 * 8 * (dim + 1) * 4)
+    # a warm snapshot: every delta row and gids plane is on its chip
+    # already, so a batch sends its queries to the four chips and no more
+    query_bytes = 4 * 8 * (dim + 1) * 4
+    report["expected_upload"] = query_bytes
 
     # against the float64 reference over the live set
     P, G = snap.live_points()
@@ -129,6 +129,21 @@ _BODY = textwrap.dedent(
                              for d in t.points.devices()}),
             "device": str(dev)})
     report["placed"] = placed
+
+    # 16 appended rows a shard (gids are consecutive, the router hashes
+    # them mod 4): each chip is sent those rows and its new view's gids
+    engine.reset_stats()
+    before = [s.deltas for s in snap.shards]
+    idx.insert_batch(X[:64] + 0.25)
+    after = [s.deltas for s in idx.snapshot().shards]
+    serve(Q[:8])
+    report["insert_counters"] = engine.stats()["span_counters"]
+    report["appended"] = [a[0].length - b[0].length
+                          for a, b in zip(after, before)]
+    report["views_after_insert"] = [len(a) for a in after]
+    report["expected_insert_upload"] = int(query_bytes + sum(
+        (a[0].length - b[0].length) * (dim + 1) * 4 + a[0].gids.nbytes
+        for a, b in zip(after, before)))
     try:
         idx.set_mesh(None)
         report["set_mesh_refused"] = False
@@ -212,6 +227,19 @@ def test_a_served_batch_uploads_no_tree_bytes(report):
     c = report["counters"]
     assert c["exchange_upload_bytes"] == report["expected_upload"]
     assert "tree_uploads" not in c
+    assert "delta_upload_bytes" not in c
+    assert c["delta_mirror_hits"] == 4
+
+
+def test_an_insert_sends_only_the_appended_rows_and_gids(report):
+    assert report["appended"] == [16, 16, 16, 16]
+    assert report["views_after_insert"] == [1, 1, 1, 1]
+    c = report["insert_counters"]
+    assert c["exchange_upload_bytes"] == report["expected_insert_upload"]
+    assert c["delta_upload_bytes"] == (
+        report["expected_insert_upload"] - 4 * 8 * 961 * 4)
+    assert c["delta_mirror_rows"] == 64
+    assert "delta_mirror_hits" not in c and "tree_uploads" not in c
 
 
 def test_exchange_spans_are_recorded(report):

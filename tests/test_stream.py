@@ -89,10 +89,10 @@ def test_delta_buffer_append_tombstone_live_rows():
     pts, gids = b.live_rows()
     assert gids.tolist() == [8] and pts.shape == (1, 3)
     # frozen view is immune to later appends/tombstones
-    _, frozen_gids, length = b.frozen_view()
+    view = b.frozen_view()
     b.append(np.zeros(3, np.float32), gid=9)
     b.tombstone(1)
-    assert frozen_gids.tolist()[:2] == [-1, 8] and length == 2
+    assert view.gids.tolist()[:2] == [-1, 8] and view.length == 2
     b.append(np.zeros(3, np.float32), gid=10)
     assert b.full
     with pytest.raises(AssertionError):
